@@ -73,7 +73,9 @@ print(json.dumps({
 
 
 def _run_child(n: int, d: int, timed_windows: int = TIMED_WINDOWS) -> dict:
-    env = dict(os.environ, PYTHONPATH=SRC)
+    # the child rehearses a forced host mesh: it stays on the CPU, so it
+    # never reaches for a chip that this (or another) process holds
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)        # the child forces its own device count
     r = subprocess.run(
         [sys.executable, "-c", _CHILD, str(n), str(d), str(timed_windows)],

@@ -1,5 +1,7 @@
-"""Pallas kernel micro-benchmarks (interpret mode — correctness-path timing;
-derived column reports the HBM bytes the fused kernel saves on real TPU).
+"""Pallas kernel micro-benchmarks.  Run on the CPU, the kernels run in the
+Pallas interpreter, so every time here is an interpreter time, not a
+device time; the derived column reports the HBM bytes the fused kernel
+would save on a TPU, computed from the shapes.
 
 Timing goes through `repro.obs.bench_kernel` (warmup + `block_until_ready`
 fenced loop).  With ``--profile [events.jsonl]`` the module installs an

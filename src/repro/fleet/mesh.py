@@ -33,7 +33,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -105,8 +104,8 @@ class FleetMesh:
         the fleet programs mix replicated PRNG-chain scans and collectives,
         and their replicated outputs are established by `psum`/`all_gather`
         by construction."""
-        return _shard_map(f, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+        return jax.shard_map(f, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

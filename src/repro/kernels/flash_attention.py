@@ -14,11 +14,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret_mode
 
 NEG_INF = -1e30
 
@@ -82,7 +85,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: int = 0,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """q (B, H, Sq, D); k, v (B, KV, Sk, D) -> (B, H, Sq, D)."""
     B, H, Sq, D = q.shape
     KV, Sk = k.shape[1], k.shape[2]
@@ -118,6 +121,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 1), jnp.float32),     # running denom l
             pltpu.VMEM((bq, D), jnp.float32),     # output accumulator
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)
     return out[:, :, :Sq]

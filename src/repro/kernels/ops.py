@@ -5,12 +5,13 @@
 pytree (one flat pass per leaf, node-seeded); `sparsify_pallas` runs the DGC
 container update on a pytree with a given keep-ratio.
 
-All wrappers take `interpret=` (True = CPU-validatable; False = real TPU).
+All wrappers take `interpret=` (None = by platform, see
+`kernels.interpret_mode`; True = interpreter; False = compiled).
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +24,7 @@ from .sparsify import sparsify_flat
 
 @partial(jax.jit, static_argnames=("causal", "window", "interpret"))
 def attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """Model layout: q (B, S, H, D); k, v (B, S, KV, D)."""
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -35,7 +36,7 @@ def attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
 
 @partial(jax.jit, static_argnames=("sigma", "clip_s", "interpret"))
 def aldp_perturb_pallas(tree, seed: jnp.ndarray, *, sigma: float,
-                        clip_s: float, interpret: bool = True):
+                        clip_s: float, interpret: Optional[bool] = None):
     """Pytree clip-at-S + Gaussian noise, fused per leaf (Eq. 8)."""
     nrm = global_norm(tree)
     scale = 1.0 / jnp.maximum(1.0, nrm / clip_s)
@@ -51,7 +52,7 @@ def aldp_perturb_pallas(tree, seed: jnp.ndarray, *, sigma: float,
 
 @partial(jax.jit, static_argnames=("ratio", "interpret"))
 def sparsify_pallas(grad_tree, residual_tree, *, ratio: float,
-                    interpret: bool = True) -> Tuple[object, object]:
+                    interpret: Optional[bool] = None) -> Tuple[object, object]:
     """DGC container update at keep-`ratio` (threshold from |combined|
     quantile, computed in jnp; the elementwise pass is the fused kernel)."""
     g_leaves, treedef = jax.tree.flatten(grad_tree)

@@ -16,11 +16,14 @@ with L innermost (sequential ⇒ carry persists).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret_mode
 
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_ref, *,
@@ -53,7 +56,7 @@ def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_ref, *,
 
 def selective_scan(x: jnp.ndarray, dt: jnp.ndarray, Bm: jnp.ndarray,
                    Cm: jnp.ndarray, A: jnp.ndarray, *, block_l: int = 128,
-                   block_d: int = 256, interpret: bool = True):
+                   block_d: int = 256, interpret: Optional[bool] = None):
     """x, dt (B, L, D); Bm, Cm (B, L, N); A (D, N).
 
     Returns (y (B, L, D), h_final (B, D, N)). The caller applies the D-skip
@@ -94,6 +97,6 @@ def selective_scan(x: jnp.ndarray, dt: jnp.ndarray, Bm: jnp.ndarray,
             jax.ShapeDtypeStruct((B, nd * bd, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, dt, Bm, Cm, A)
     return y[:, :L, :D], h[:, :D]

@@ -7,12 +7,14 @@ kernel does one read of (g, residual) and one write of (upload, residual').
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANE = 1024
+from . import LANE, block_layout, interpret_mode, pad_blocks
 
 
 def _kernel(thr_ref, g_ref, r_ref, up_ref, newr_ref):
@@ -33,42 +35,29 @@ def _fleet_kernel(thr_ref, g_ref, r_ref, up_ref, newr_ref):
 
 def sparsify_flat(grad: jnp.ndarray, residual: jnp.ndarray,
                   threshold: jnp.ndarray, *, block_rows: int = 256,
-                  interpret: bool = True):
+                  interpret: Optional[bool] = None):
     """grad, residual (N,); threshold () f32 -> (upload (N,), residual' (N,))."""
     n = grad.shape[0]
-    cols = LANE
-    rows_total = -(-n // cols)
-    pad = rows_total * cols - n
-    g = jnp.pad(grad, (0, pad)).reshape(rows_total, cols)
-    r = jnp.pad(residual, (0, pad)).reshape(rows_total, cols)
-    nb = -(-rows_total // block_rows)
-    pad_r = nb * block_rows - rows_total
-    if pad_r:
-        g = jnp.pad(g, ((0, pad_r), (0, 0)))
-        r = jnp.pad(r, ((0, pad_r), (0, 0)))
+    rows, block_rows, nb = block_layout(n, block_rows)
+    g = pad_blocks(grad, rows, block_rows, nb)
+    r = pad_blocks(residual, rows, block_rows, nb)
+    blk = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
 
     up, newr = pl.pallas_call(
         _kernel,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
-        ],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), blk, blk],
+        out_specs=[blk, blk],
         out_shape=[jax.ShapeDtypeStruct(g.shape, grad.dtype),
                    jax.ShapeDtypeStruct(g.shape, residual.dtype)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(threshold.reshape(1).astype(jnp.float32), g, r)
     return up.reshape(-1)[:n], newr.reshape(-1)[:n]
 
 
 def sparsify_fleet(grads: jnp.ndarray, residuals: jnp.ndarray,
                    thresholds: jnp.ndarray, *, block_rows: int = 256,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """Whole-cohort DGC pass: one kernel launch for every node's upload split.
 
     grads, residuals (K, N); thresholds (K,) f32 — per-node magnitude cutoffs.
@@ -76,31 +65,18 @@ def sparsify_fleet(grads: jnp.ndarray, residuals: jnp.ndarray,
     cohort shares a single device program instead of K dispatches.
     """
     k, n = grads.shape
-    cols = LANE
-    rows_total = -(-n // cols)
-    pad = rows_total * cols - n
-    g = jnp.pad(grads, ((0, 0), (0, pad))).reshape(k, rows_total, cols)
-    r = jnp.pad(residuals, ((0, 0), (0, pad))).reshape(k, rows_total, cols)
-    nb = -(-rows_total // block_rows)
-    pad_r = nb * block_rows - rows_total
-    if pad_r:
-        g = jnp.pad(g, ((0, 0), (0, pad_r), (0, 0)))
-        r = jnp.pad(r, ((0, 0), (0, pad_r), (0, 0)))
+    rows, block_rows, nb = block_layout(n, block_rows)
+    g = pad_blocks(grads, rows, block_rows, nb)
+    r = pad_blocks(residuals, rows, block_rows, nb)
+    blk = pl.BlockSpec((1, block_rows, LANE), lambda i, j: (i, j, 0))
 
     up, newr = pl.pallas_call(
         _fleet_kernel,
         grid=(k, nb),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_rows, cols), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_rows, cols), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_rows, cols), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_rows, cols), lambda i, j: (i, j, 0)),
-        ],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), blk, blk],
+        out_specs=[blk, blk],
         out_shape=[jax.ShapeDtypeStruct(g.shape, grads.dtype),
                    jax.ShapeDtypeStruct(g.shape, residuals.dtype)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(thresholds.astype(jnp.float32), g, r)
     return (up.reshape(k, -1)[:, :n], newr.reshape(k, -1)[:, :n])

@@ -13,11 +13,14 @@ broadcast over heads); A (H,). Grid (B, H/bh, L/c), L innermost.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret_mode
 
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_ref, *,
@@ -63,7 +66,7 @@ def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_ref, *,
 
 def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, Bm: jnp.ndarray,
              Cm: jnp.ndarray, A: jnp.ndarray, *, chunk: int = 128,
-             block_h: int = 8, interpret: bool = True):
+             block_h: int = 8, interpret: Optional[bool] = None):
     """Returns (y (B, L, H, P), h_final (B, H, P, N)).
 
     Caller applies the D-skip and gated norm (`models.ssm.mamba2_fwd`)."""
@@ -102,6 +105,6 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, Bm: jnp.ndarray,
             jax.ShapeDtypeStruct((B, nh * bh, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bh, P, N), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, dt, Bm, Cm, A)
     return y[:, :L, :H], h[:, :H]

@@ -45,7 +45,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ldp_noise import LANE, _hash_uniform
+from . import LANE, block_layout, interpret_mode, pad_blocks
+from .ldp_noise import _finalize_uniform, _hash_uniform
+from .wire_bytes import accumulate_nnz, nnz_out
 
 
 def _fused_kernel(*refs, sigma_s: float, apply_ldp: bool, do_sparsify: bool,
@@ -85,11 +87,7 @@ def _fused_kernel(*refs, sigma_s: float, apply_ldp: bool, do_sparsify: bool,
     else:
         up = g
     if need_nnz:
-        cnt = jnp.sum(up != 0.0).astype(jnp.int32)
-        @pl.when(blk == 0)
-        def _init():
-            nnz_ref[0, 0] = 0
-        nnz_ref[0, 0] += cnt
+        accumulate_nnz(nnz_ref, blk, up)
     if apply_ldp:
         up = up * scale_ref[node]
         if sigma_s > 0.0:
@@ -103,17 +101,6 @@ def _fused_kernel(*refs, sigma_s: float, apply_ldp: bool, do_sparsify: bool,
     up_ref[0] = up.astype(up_ref.dtype)
 
 
-def _pad_cohort(a: jnp.ndarray, rows_total: int, nb: int, block_rows: int,
-                cols: int) -> jnp.ndarray:
-    k, n = a.shape
-    x = jnp.pad(a, ((0, 0), (0, rows_total * cols - n))
-                ).reshape(k, rows_total, cols)
-    pad_r = nb * block_rows - rows_total
-    if pad_r:
-        x = jnp.pad(x, ((0, 0), (0, pad_r), (0, 0)))
-    return x
-
-
 def upload_fused_fleet(flat: jnp.ndarray,
                        residuals: Optional[jnp.ndarray],
                        thresholds: Optional[jnp.ndarray],
@@ -122,7 +109,8 @@ def upload_fused_fleet(flat: jnp.ndarray,
                        sigma: float, clip_s: float, *,
                        boundaries: Sequence[int] = (0,),
                        need_nnz: bool = False,
-                       block_rows: int = 256, interpret: bool = True):
+                       block_rows: int = 256,
+                       interpret: Optional[bool] = None):
     """Whole-cohort fused upload pipeline: one kernel launch for every
     node's sparsify + nnz + clip + noise.
 
@@ -139,16 +127,14 @@ def upload_fused_fleet(flat: jnp.ndarray,
     None) — bit-equal to the unfused sparsify/nnz/ldp kernel chain.
     """
     k, n = flat.shape
-    cols = LANE
-    rows_total = -(-n // cols)
-    nb = -(-rows_total // block_rows)
+    rows, block_rows, nb = block_layout(n, block_rows)
     do_sparsify = residuals is not None
     apply_ldp = clip_scales is not None
     sigma_s = float(sigma) * float(clip_s) if apply_ldp else 0.0
 
     args, in_specs = [], []
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    blkspec = pl.BlockSpec((1, block_rows, cols), lambda i, j: (i, j, 0))
+    blkspec = pl.BlockSpec((1, block_rows, LANE), lambda i, j: (i, j, 0))
     if do_sparsify:
         args.append(thresholds.astype(jnp.float32))
         in_specs.append(smem)
@@ -158,11 +144,11 @@ def upload_fused_fleet(flat: jnp.ndarray,
     if apply_ldp:
         args.append(clip_scales.astype(jnp.float32))
         in_specs.append(smem)
-    x = _pad_cohort(flat, rows_total, nb, block_rows, cols)
+    x = pad_blocks(flat, rows, block_rows, nb)
     args.append(x)
     in_specs.append(blkspec)
     if do_sparsify:
-        args.append(_pad_cohort(residuals, rows_total, nb, block_rows, cols))
+        args.append(pad_blocks(residuals, rows, block_rows, nb))
         in_specs.append(blkspec)
 
     out_specs = [blkspec]
@@ -171,8 +157,9 @@ def upload_fused_fleet(flat: jnp.ndarray,
         out_specs.append(blkspec)
         out_shape.append(jax.ShapeDtypeStruct(x.shape, residuals.dtype))
     if need_nnz:
-        out_specs.append(pl.BlockSpec((1, 1), lambda i, j: (i, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((k, 1), jnp.int32))
+        nnz_spec, nnz_shape = nnz_out(k)
+        out_specs.append(nnz_spec)
+        out_shape.append(nnz_shape)
 
     kernel = functools.partial(
         _fused_kernel, sigma_s=sigma_s, apply_ldp=apply_ldp,
@@ -180,11 +167,11 @@ def upload_fused_fleet(flat: jnp.ndarray,
         boundaries=tuple(int(b) for b in boundaries))
     outs = pl.pallas_call(
         kernel, grid=(k, nb), in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret)(*args)
+        out_shape=out_shape, interpret=interpret_mode(interpret))(*args)
     outs = list(outs)
     up = outs.pop(0).reshape(k, -1)[:, :n]
     newr = outs.pop(0).reshape(k, -1)[:, :n] if do_sparsify else None
-    nnz = outs.pop(0).reshape(k) if need_nnz else None
+    nnz = outs.pop(0)[:, 0, 0] if need_nnz else None
     return up, newr, nnz
 
 
@@ -198,26 +185,20 @@ def block_noise(k: int, n: int, seeds: jnp.ndarray, sigma_s: float, *,
     plain jnp over the same padded (rows, LANE) layout: element e of block b
     of node i draws from hash(seeds[i] + b·7919, stream, e) exactly as the
     in-kernel generator does.  Returns the (k, n) noise the kernel adds."""
-    cols = LANE
-    rows_total = -(-n // cols)
-    r = jnp.arange(rows_total, dtype=jnp.int32)
+    rows, block_rows, _ = block_layout(n, block_rows)
+    r = jnp.arange(rows, dtype=jnp.int32)
     blk = r // block_rows
     in_blk = (r % block_rows).astype(jnp.uint32)
-    col = jnp.arange(cols, dtype=jnp.uint32)
+    col = jnp.arange(LANE, dtype=jnp.uint32)
     # in-block element index, matching the kernel's broadcasted_iota layout
-    x_idx = in_blk[:, None] * jnp.uint32(cols) + col[None, :]
+    x_idx = in_blk[:, None] * jnp.uint32(LANE) + col[None, :]
     blk_seed = (seeds.astype(jnp.int32)[:, None, None]
                 + blk[None, :, None] * 7919)
 
     def hash_u(stream: int) -> jnp.ndarray:
         x = x_idx[None] + blk_seed.astype(jnp.uint32) * jnp.uint32(2654435761)
         x = x + jnp.uint32((stream * 0x9E3779B9) & 0xFFFFFFFF)
-        x = x ^ (x >> 16)
-        x = x * jnp.uint32(0x7FEB352D)
-        x = x ^ (x >> 15)
-        x = x * jnp.uint32(0x846CA68B)
-        x = x ^ (x >> 16)
-        return (x >> 8).astype(jnp.float32) / jnp.float32(1 << 24)
+        return _finalize_uniform(x)
 
     u1 = jnp.maximum(hash_u(1), 1e-12)
     u2 = hash_u(2)

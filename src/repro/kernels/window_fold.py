@@ -29,12 +29,14 @@ selection (a gated-off arrival leaves params bitwise untouched).
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ldp_noise import LANE
+from . import LANE, block_layout, interpret_mode, pad_blocks
 
 
 def _fold_kernel(gate_ref, a_ref, b_ref, p_ref, om_ref, seq_ref, out_ref):
@@ -52,7 +54,8 @@ def _fold_kernel(gate_ref, a_ref, b_ref, p_ref, om_ref, seq_ref, out_ref):
 
 def window_fold_fleet(p_flat: jnp.ndarray, om_flat: jnp.ndarray,
                       gates: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray, *,
-                      block_rows: int = 256, interpret: bool = True):
+                      block_rows: int = 256,
+                      interpret: Optional[bool] = None):
     """Fold a window of arrivals into the flattened global params.
 
     p_flat (N,) f32 params; om_flat (C, N) f32 per-arrival node models in
@@ -63,35 +66,21 @@ def window_fold_fleet(p_flat: jnp.ndarray, om_flat: jnp.ndarray,
     Returns (final params (N,), per-arrival snapshots (C, N)).
     """
     c, n = om_flat.shape
-    cols = LANE
-    rows_total = -(-n // cols)
-    pad = rows_total * cols - n
-    nb = -(-rows_total // block_rows)
-    pad_r = nb * block_rows - rows_total
-    p = jnp.pad(p_flat.astype(jnp.float32), (0, pad)).reshape(rows_total,
-                                                              cols)
-    om = jnp.pad(om_flat.astype(jnp.float32),
-                 ((0, 0), (0, pad))).reshape(c, rows_total, cols)
-    if pad_r:
-        p = jnp.pad(p, ((0, pad_r), (0, 0)))
-        om = jnp.pad(om, ((0, 0), (0, pad_r), (0, 0)))
+    rows, block_rows, nb = block_layout(n, block_rows)
+    p = pad_blocks(p_flat.astype(jnp.float32), rows, block_rows, nb)
+    om = pad_blocks(om_flat.astype(jnp.float32), rows, block_rows, nb)
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    p_blk = pl.BlockSpec((block_rows, LANE), lambda j, i: (j, 0))
+    om_blk = pl.BlockSpec((1, block_rows, LANE), lambda j, i: (i, j, 0))
     seq, final = pl.pallas_call(
         _fold_kernel,
         grid=(nb, c),
-        in_specs=[
-            smem, smem, smem,
-            pl.BlockSpec((block_rows, cols), lambda j, i: (j, 0)),
-            pl.BlockSpec((1, block_rows, cols), lambda j, i: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_rows, cols), lambda j, i: (i, j, 0)),
-            pl.BlockSpec((block_rows, cols), lambda j, i: (j, 0)),
-        ],
+        in_specs=[smem, smem, smem, p_blk, om_blk],
+        out_specs=[om_blk, p_blk],
         out_shape=[jax.ShapeDtypeStruct(om.shape, jnp.float32),
                    jax.ShapeDtypeStruct(p.shape, jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(gates.astype(jnp.int32), a.astype(jnp.float32),
       b.astype(jnp.float32), p, om)
     return final.reshape(-1)[:n], seq.reshape(c, -1)[:, :n]

@@ -30,6 +30,16 @@ def init_cnn(key, in_hw: Tuple[int, int] = (28, 28), in_ch: int = 1,
     }
 
 
+def cnn_flat_layout(in_hw: Tuple[int, int] = (28, 28)
+                    ) -> Tuple[int, Tuple[int, ...]]:
+    """(P, start offset of each leaf) of the CNN's params flattened in leaf
+    order: the (C, P) layout the fleet's cohort kernels see."""
+    shapes = jax.eval_shape(lambda k: init_cnn(k, in_hw=in_hw),
+                            jax.random.PRNGKey(0))
+    sizes = [int(l.size) for l in jax.tree.leaves(shapes)]
+    return sum(sizes), tuple(sum(sizes[:i]) for i in range(len(sizes)))
+
+
 def cnn_forward(params: dict, x: jnp.ndarray) -> jnp.ndarray:
     """x (B, H, W, C) -> logits (B, n_classes)."""
     def conv(p, h, stride):
@@ -44,22 +54,33 @@ def cnn_forward(params: dict, x: jnp.ndarray) -> jnp.ndarray:
     return h @ params["fc"]["w"].astype(h.dtype) + params["fc"]["b"].astype(h.dtype)
 
 
+def predicted_class(logits: jnp.ndarray) -> jnp.ndarray:
+    """`logits.argmax(-1)`, the first index of each row's maximum, written
+    as max / compare / min.  On TPU v5e an argmax fused into an accuracy
+    vmapped over a cohort whose size is not a multiple of 8 returns wrong
+    classes, and those accuracies are Alg. 2's detection scores; this form
+    is exact there and bit-equal to argmax wherever no logit is NaN."""
+    n = logits.shape[-1]
+    top = logits.max(-1, keepdims=True)
+    return jnp.where(logits == top, jnp.arange(n), n).min(-1)
+
+
 def cnn_loss(params: dict, batch: dict) -> Tuple[jnp.ndarray, dict]:
     logits = cnn_forward(params, batch["x"])
     labels = batch["y"]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32))
     nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1).mean()
-    acc = (logits.argmax(-1) == labels).mean()
+    acc = (predicted_class(logits) == labels).mean()
     return nll, {"accuracy": acc}
 
 
 def cnn_accuracy(params: dict, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
-    return (cnn_forward(params, x).argmax(-1) == y).mean()
+    return (predicted_class(cnn_forward(params, x)) == y).mean()
 
 
 def per_class_accuracy(params: dict, x: jnp.ndarray, y: jnp.ndarray,
                        cls: int) -> jnp.ndarray:
     """Accuracy restricted to one class (the paper's 'special task')."""
-    pred = cnn_forward(params, x).argmax(-1)
+    pred = predicted_class(cnn_forward(params, x))
     sel = (y == cls)
     return jnp.where(sel, pred == y, 0).sum() / jnp.maximum(sel.sum(), 1)

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .cnn import predicted_class
+
 
 def init_mlp(key, in_dim: int, hidden: int = 32, n_classes: int = 10) -> dict:
     k1, k2 = jax.random.split(key)
@@ -37,11 +39,11 @@ def mlp_loss(params: dict, batch: dict) -> Tuple[jnp.ndarray, dict]:
     logp = jax.nn.log_softmax(logits)
     y = batch["y"].astype(jnp.int32)
     loss = -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
-    acc = jnp.mean((logits.argmax(-1) == y).astype(jnp.float32))
+    acc = jnp.mean((predicted_class(logits) == y).astype(jnp.float32))
     return loss, {"accuracy": acc}
 
 
 def mlp_accuracy(params: dict, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     logits = mlp_forward(params, x)
-    return jnp.mean((logits.argmax(-1) == y.astype(jnp.int32))
+    return jnp.mean((predicted_class(logits) == y.astype(jnp.int32))
                     .astype(jnp.float32))

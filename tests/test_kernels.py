@@ -4,8 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import LANE, block_layout, interpret_mode
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ldp_noise import ldp_perturb_flat
+from repro.kernels.ldp_noise import _finalize_uniform, ldp_perturb_flat
 from repro.kernels.ops import (aldp_perturb_pallas, attention_pallas,
                                sparsify_pallas)
 from repro.kernels.ref import (flash_attention_ref, ldp_perturb_flat_ref,
@@ -104,6 +105,46 @@ def test_ldp_kernel_noise_statistics():
     out2 = ldp_perturb_flat(jnp.zeros(500000), jnp.int32(12), jnp.float32(1.0),
                             0.3, 2.0)
     assert abs(float(np.corrcoef(x, np.asarray(out2))[0, 1])) < 0.01
+
+
+def test_ldp_noise_stream_independent_of_block_clamp():
+    """`block_layout` clamps block_rows to the rows a vector needs; a vector
+    that fits one block keeps its in-block indices, so its noise is the
+    prefix of a full 256-row block's noise."""
+    full = ldp_perturb_flat(jnp.zeros(256 * LANE), jnp.int32(9),
+                            jnp.float32(1.0), 0.3, 1.0)
+    for n in (100, 20490, 70000):
+        out = ldp_perturb_flat(jnp.zeros(n), jnp.int32(9), jnp.float32(1.0),
+                               0.3, 1.0)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(full)[:n])
+
+
+def test_uniform_cast_through_int32_is_exact():
+    """The TPU has no u32 -> f32 convert; the 24-bit value goes through
+    int32 instead, which must give the direct cast's values bit for bit."""
+    x = jnp.concatenate([jnp.arange(1 << 16, dtype=jnp.uint32),
+                         jax.random.bits(jax.random.PRNGKey(0), (1 << 16,),
+                                         jnp.uint32),
+                         jnp.array([0xFFFFFFFF], jnp.uint32)])
+    got = _finalize_uniform(x)
+    y = x ^ (x >> 16)
+    y = y * jnp.uint32(0x7FEB352D)
+    y = y ^ (y >> 15)
+    y = y * jnp.uint32(0x846CA68B)
+    y = y ^ (y >> 16)
+    want = (y >> 8).astype(jnp.float32) / jnp.float32(1 << 24)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert float(got.max()) < 1.0
+
+
+@pytest.mark.parametrize("n,block_rows,want", [
+    (20490, 256, (21, 24, 1)),      # paper CNN: one 24-row block
+    (100, 256, (1, 8, 1)),
+    (300 * LANE, 256, (300, 256, 2)),
+    (4097, 2, (5, 2, 3)),           # below the clamp: unchanged
+])
+def test_block_layout_clamps_to_the_vector(n, block_rows, want):
+    assert block_layout(n, block_rows) == want
 
 
 def test_ldp_ops_matches_core_clipping():
@@ -238,3 +279,100 @@ def test_sparsify_ops_matches_accumulator():
     tot_in = jax.tree.map(lambda a, b: a + b, g, r)
     for x, y in zip(jax.tree.leaves(tot_k), jax.tree.leaves(tot_in)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# interpret mode follows the platform; the chip smoke test needs a chip
+# ---------------------------------------------------------------------------
+
+def test_interpret_mode_follows_platform(monkeypatch):
+    assert jax.default_backend() == "cpu"
+    assert interpret_mode() is True
+    assert interpret_mode(False) is False and interpret_mode(True) is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        interpret_mode()
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    import os
+    import subprocess
+    import sys
+    repo = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(repo, "chip_smoke.py")],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert '"ok"' not in r.stdout
+
+
+def test_chip_smoke_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache goes
+    to the fixed <repo>/.jax_cache."""
+    import os
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(repo)
+    import chip_smoke as smoke
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert smoke.place_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(repo, ".jax_cache")
+        assert smoke.place_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def _audit_drive(verdicts, params=0.0):
+    import chip_smoke as smoke
+    from repro.api.report import RoundRecord
+    recs = [RoundRecord(t=float(k), version=k, accuracy=0.5, comm_bytes=0.0,
+                        comp_time=0.0, comm_time=0.0,
+                        n_rejected=sum(t["rejected"] for t in v))
+            for k, v in enumerate(verdicts)]
+    snaps = [{"w": np.full(3, params, np.float32)} for _ in verdicts]
+    return smoke.Drive(0.0, [], recs, snaps[-1], snaps, None, verdicts)
+
+
+def _verdict(window, node, acc, thr):
+    return {"window": window, "node": node, "accuracy": acc,
+            "threshold": thr, "rejected": not acc > thr}
+
+
+@pytest.mark.parametrize("case", ["agree", "tie_flip", "wide_flip",
+                                  "score_gap", "param_gap"])
+def test_chip_smoke_mesh_hold(monkeypatch, case):
+    """The --mesh comparison holds scores, thresholds and verdicts up to
+    the first flipped verdict (which must be a tie; later windows of that
+    record are not compared) and params of every record before it."""
+    import os
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(repo)
+    import chip_smoke as smoke
+    rec1 = [_verdict(0, 0, 0.50, 0.40), _verdict(0, 1, 0.30, 0.40)]
+    rec2 = [_verdict(1, 0, 0.5504, 0.55), _verdict(2, 1, 0.70, 0.55)]
+    base = _audit_drive([rec1, rec2])
+    other = [[dict(t) for t in rec1], [dict(t) for t in rec2]]
+    # the window after a flip folds different uploads: never compared
+    other[1][1]["accuracy"] = 0.1
+    other[1][1]["rejected"] = True
+    if case == "agree":
+        other[1][1] = dict(rec2[1])
+    elif case == "tie_flip":
+        other[1][0].update(accuracy=0.5499, rejected=True)
+    elif case == "wide_flip":
+        other[1][0].update(accuracy=0.45, rejected=True)
+    elif case == "score_gap":
+        other[0][0]["accuracy"] = 0.47
+    res = smoke.hold(base, _audit_drive(
+        other, params=2e-5 if case == "param_gap" else 0.0))
+    want = {"agree": (True, 2), "tie_flip": (True, 1),
+            "wide_flip": (False, 1), "score_gap": (False, 1),
+            "param_gap": (False, 1)}[case]
+    assert (res["ok"], res["held_records"]) == want, res["verdicts"]

@@ -92,3 +92,48 @@ def test_param_count_analytic_close():
         actual = sum(x.size for x in jax.tree.leaves(params))
         est = cfg.n_params()
         assert abs(actual - est) / actual < 0.2, (arch, actual, est)
+
+
+# ---------------------------------------------------------------------------
+# edge models: the argmax-free prediction that scores uploads (Alg. 2)
+# ---------------------------------------------------------------------------
+
+def test_predicted_class_is_first_argmax():
+    from repro.models.cnn import predicted_class
+    key = jax.random.PRNGKey(3)
+    # values on a coarse grid so that rows tie, including at the maximum
+    logits = jnp.round(jax.random.normal(key, (7, 250, 10)) * 2) / 2
+    got = predicted_class(logits)
+    want = logits.argmax(-1)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    ties = (logits == logits.max(-1, keepdims=True)).sum(-1) > 1
+    assert bool(ties.any())
+
+
+@pytest.mark.parametrize("model", ["cnn", "mlp"])
+@pytest.mark.parametrize("cohort", [3, 10, 250])
+def test_edge_accuracy_bit_equal_to_argmax_form(model, cohort):
+    """The vmapped per-node accuracies (the engines' detection scores) are
+    the argmax form's, bit for bit, at cohort sizes on and off a multiple
+    of 8."""
+    from repro.models.cnn import cnn_accuracy, cnn_forward, init_cnn
+    from repro.models.mlp import init_mlp, mlp_accuracy, mlp_forward
+    kp, kd, kx, ky = jax.random.split(jax.random.PRNGKey(5), 4)
+    if model == "cnn":
+        params, fwd, acc = init_cnn(kp, in_hw=(8, 8)), cnn_forward, cnn_accuracy
+        x = jax.random.normal(kx, (64, 8, 8, 1))
+    else:
+        params, fwd, acc = init_mlp(kp, 64), mlp_forward, mlp_accuracy
+        x = jax.random.normal(kx, (64, 64))
+    y = jax.random.randint(ky, (64,), 0, 10)
+    keys = jax.random.split(kd, len(jax.tree.leaves(params)))
+    cohort_params = jax.tree.map(
+        lambda p, k: p[None] + 0.3 * jax.random.normal(k, (cohort,) + p.shape),
+        params, jax.tree.unflatten(jax.tree.structure(params), list(keys)))
+    got = jax.jit(jax.vmap(lambda p: acc(p, x, y)))(cohort_params)
+    want = jax.jit(jax.vmap(
+        lambda p: jnp.mean((fwd(p, x).argmax(-1) == y).astype(jnp.float32))
+    ))(cohort_params)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert len(set(np.asarray(got).tolist())) > 1
