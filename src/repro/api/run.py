@@ -293,6 +293,7 @@ class _SyncFleetStepper:
         eng.load_state(fleet.stack_trees(state.residuals), state.key)
         self.emitted = 0
         self.pre_step = None
+        self.gc = _obs.GcSpans()    # Python's collections during steps
 
     @property
     def net(self):
@@ -310,16 +311,20 @@ class _SyncFleetStepper:
         if self.pre_step is not None:
             self.pre_step(self)
         state, eng = self.state, self.eng
-        rec = eng.run_round()
-        if state.accountant is not None:
-            # charge only the nodes that actually uploaded a noised delta
-            # (cohort sampling / availability: n_participating <= n_nodes)
-            state.accountant.step(rec.n_participating)
+        with self.gc:
+            rec = eng.run_round(on_record=self._charge)
         state.params = eng.params
         state.history.append(RoundRecord(
             rec.t, self.emitted, rec.accuracy, rec.comm_bytes, rec.comp_time,
             rec.comm_time, rec.n_rejected, bytes_source=self.src))
         self.emitted += 1
+
+    def _charge(self, rec) -> None:
+        """Charge only the nodes that actually uploaded a noised delta
+        (cohort sampling / availability: n_participating <= n_nodes)."""
+        if self.state.accountant is not None:
+            with _obs.timed_stage(self.eng.obs, "record.accountant"):
+                self.state.accountant.step(rec.n_participating)
 
     def finalize(self) -> None:
         _fleet_handback(self.state, self.eng, self.n)
@@ -362,6 +367,7 @@ class _AsyncFleetStepper:
         self.emitted = 0
         self.processed = 0
         self.pre_step = None
+        self.gc = _obs.GcSpans()    # Python's collections during steps
 
     @property
     def net(self):
@@ -377,6 +383,15 @@ class _AsyncFleetStepper:
         return float(arr.min())
 
     def step(self) -> None:
+        with self.gc:
+            self._step()
+
+    def _charge(self, rec) -> None:
+        if self.state.accountant is not None:
+            with _obs.timed_stage(self.eng.obs, "record.accountant"):
+                self.state.accountant.step(rec.n_processed)
+
+    def _step(self) -> None:
         state, eng, n = self.state, self.eng, self.n
         target = min(self.processed + n, self.plan.total_arrivals)
         span_bytes = span_comp = span_comm = 0.0
@@ -388,8 +403,7 @@ class _AsyncFleetStepper:
             rec = eng.run_window(max_arrivals=target - self.processed,
                                  evaluate=False)
             self.processed += rec.n_processed
-            if state.accountant is not None:
-                state.accountant.step(rec.n_processed)
+            self._charge(rec)
             state.params = eng.params
             span_bytes += rec.comm_bytes
             span_comp += rec.comp_time
@@ -429,7 +443,7 @@ class _BufferedFleetStepper(_AsyncFleetStepper):
     by window without the event-loop record boundary — one record per
     window (load-aware policies make windows fat on purpose)."""
 
-    def step(self) -> None:
+    def _step(self) -> None:
         if self.pre_step is not None:
             self.pre_step(self)
         state, eng = self.state, self.eng
@@ -437,8 +451,7 @@ class _BufferedFleetStepper(_AsyncFleetStepper):
             max_arrivals=self.plan.total_arrivals - self.processed,
             evaluate=False)
         self.processed += rec.n_processed
-        if state.accountant is not None:
-            state.accountant.step(rec.n_processed)
+        self._charge(rec)
         state.params = eng.params
         state.history.append(RoundRecord(
             rec.t, rec.version,

@@ -248,9 +248,12 @@ class ObsSpec:
         stream replays back into the exact final report
         (`report.replay_records`);
       * ``stage_timings`` — `block_until_ready`-fenced spans around each
-        host pipeline stage (build/device program/net draw+commit/eval).
-        Off by default even when tracing: fencing serializes JAX's async
-        dispatch, an intentional measurement-mode perf change;
+        host pipeline stage (device program, read-backs, net
+        draw+commit, eval, accounting) as `obs` events.  Off by default
+        even when tracing: fencing serializes JAX's async dispatch, an
+        intentional measurement-mode perf change.  The same stages are
+        unfenced `jax.profiler` annotations in every run, with or
+        without this spec;
       * ``health``        — optional `repro.obs.HealthSpec`: declarative
         SLO probes (straggler factor, per-record byte budget, detection
         reject-rate ceiling, occupancy floor) evaluated between records,

@@ -45,7 +45,7 @@ import numpy as np
 
 from ..core import async_update, detection
 from ..obs import (STALENESS_EDGES, WINDOW_SIZE_EDGES, get_tracer,
-                   timed_stage)
+                   host_span, timed_stage)
 from . import mesh as mesh_lib
 from . import stages
 from .engine import ClientSampler, FleetConfig, NodeProfile
@@ -336,7 +336,9 @@ class AsyncFleetEngine(MeshStateIO):
         self.obs = tracer if tracer is not None else get_tracer()
         self._need_audit = self.obs.enabled
         self.loss_fn = loss_fn
-        self.acc_fn = jax.jit(acc_fn)
+        # the test-set pass's scope; inside the window program, which also
+        # scores the uploads with it, those ops count to the cloud score
+        self.acc_fn = jax.jit(stages.scoped(stages.EVALUATE, acc_fn))
         (self.data, self.n_nodes, self.test_data, self.cloud_test,
          self.profile, self.n_params) = stages.init_engine_common(
             init_params, node_data, test_data, cloud_test, profile)
@@ -421,43 +423,50 @@ class AsyncFleetEngine(MeshStateIO):
             in-window flags (sorted positions); avail: churn mask; up_s:
             per-slot uplink transfer seconds (the fixed analytic per-node
             times, or the network simulator's per-upload draws)."""
-            t_arr = jnp.take(state.next_arrival, order)
-            vdisp_c = jnp.take(state.dispatched_version, order)
-            disp_c = gather_nodes(state.dispatched, order)
-            res_c = gather_nodes(state.residuals, order)
-            xg = jnp.take(x, order, axis=0)
-            yg = jnp.take(y, order, axis=0)
-            sz = jnp.take(sizes, order, axis=0)
+            with jax.named_scope(stages.FOLD):
+                t_arr = jnp.take(state.next_arrival, order)
+                vdisp_c = jnp.take(state.dispatched_version, order)
+            with jax.named_scope(stages.LOCAL_SGD):
+                disp_c = gather_nodes(state.dispatched, order)
+            with jax.named_scope(stages.UPLOAD):
+                res_c = gather_nodes(state.residuals, order)
+            with jax.named_scope(stages.LOCAL_SGD):
+                xg = jnp.take(x, order, axis=0)
+                yg = jnp.take(y, order, axis=0)
+                sz = jnp.take(sizes, order, axis=0)
 
-            if cfg.key_mode == "sequential":
-                chain_key, k1s, k2s = chain_node_keys_masked(
-                    state.chain_key, proc)
-            else:
-                chain_key, k1s, k2s = parallel_node_keys(state.chain_key,
-                                                         order.shape[0])
+                if cfg.key_mode == "sequential":
+                    chain_key, k1s, k2s = chain_node_keys_masked(
+                        state.chain_key, proc)
+                else:
+                    chain_key, k1s, k2s = parallel_node_keys(
+                        state.chain_key, order.shape[0])
 
-            local = jax.vmap(local_train)(disp_c, xg, yg, sz, k1s)
-            deltas = jax.tree.map(lambda l, d: l - d.astype(l.dtype),
-                                  local, disp_c)
-            if attack_stage is not None:
-                mal_c = jnp.take(mal_full, order)
-                thr_c = (jnp.take(state.throttle, order)
-                         if state.throttle is not None else None)
-                deltas = attack_stage(deltas, mal_c, thr_c)
-            deltas, res_c, nnz = stages.upload_pipeline(cfg, deltas, res_c,
-                                                        k2s,
-                                                        need_nnz=need_nnz)
-            omegas, accs = stages.rebuild_and_evaluate(
-                raw_acc_fn, disp_c, deltas, cloud_x, cloud_y)
+                local = jax.vmap(local_train)(disp_c, xg, yg, sz, k1s)
+                deltas = jax.tree.map(lambda l, d: l - d.astype(l.dtype),
+                                      local, disp_c)
+            with jax.named_scope(stages.UPLOAD):
+                if attack_stage is not None:
+                    mal_c = jnp.take(mal_full, order)
+                    thr_c = (jnp.take(state.throttle, order)
+                             if state.throttle is not None else None)
+                    deltas = attack_stage(deltas, mal_c, thr_c)
+                deltas, res_c, nnz = stages.upload_pipeline(
+                    cfg, deltas, res_c, k2s, need_nnz=need_nnz)
+            with jax.named_scope(stages.CLOUD_SCORE):
+                omegas, accs = stages.rebuild_and_evaluate(
+                    raw_acc_fn, disp_c, deltas, cloud_x, cloud_y)
 
             arrived = proc & avail
-            trust_c = (jnp.take(state.trust, order)
-                       if state.trust is not None else None)
-            fold = (sequential_fold if cfg.mixing == "sequential"
-                    else buffered_fold)
-            params, version, ring, count, p_seq, v_seq, rej, taus, aud = \
-                fold(params, state.version, state.acc_ring, state.acc_count,
-                     omegas, accs, vdisp_c, arrived, trust_c=trust_c)
+            with jax.named_scope(stages.FOLD):
+                trust_c = (jnp.take(state.trust, order)
+                           if state.trust is not None else None)
+                fold = (sequential_fold if cfg.mixing == "sequential"
+                        else buffered_fold)
+                params, version, ring, count, p_seq, v_seq, rej, taus, aud \
+                    = fold(params, state.version, state.acc_ring,
+                           state.acc_count, omegas, accs, vdisp_c, arrived,
+                           trust_c=trust_c)
 
             # redispatch: processed nodes get the model right after their
             # own slot (sequential) / the post-window model (buffered), the
@@ -466,26 +475,32 @@ class AsyncFleetEngine(MeshStateIO):
             drop_idx = jnp.where(proc, order, n)
             scatter = lambda full, part: jax.tree.map(
                 lambda f, p: f.at[drop_idx].set(p, mode="drop"), full, part)
-            dispatched = scatter(state.dispatched, p_seq)
-            residuals = scatter(state.residuals, res_c)
-            dv = state.dispatched_version.at[drop_idx].set(v_seq, mode="drop")
-            t_next = t_arr + up_s + jnp.take(comp_s, order)
-            na = state.next_arrival.at[drop_idx].set(t_next, mode="drop")
+            with jax.named_scope(stages.FOLD):
+                dispatched = scatter(state.dispatched, p_seq)
+            with jax.named_scope(stages.UPLOAD):
+                residuals = scatter(state.residuals, res_c)
+            with jax.named_scope(stages.FOLD):
+                dv = state.dispatched_version.at[drop_idx].set(v_seq,
+                                                               mode="drop")
+                t_next = t_arr + up_s + jnp.take(comp_s, order)
+                na = state.next_arrival.at[drop_idx].set(t_next, mode="drop")
 
-            # trust EWMA / adaptive-attacker throttle, from this window's
-            # verdicts (only arrived slots were judged; churned slots keep
-            # their scores — trust_update's `seen` mask is the identity
-            # for them, so the proc-indexed scatter is harmless)
-            trust = state.trust
-            if trust is not None:
-                t_new = detection.trust_update(trust_c, arrived & ~rej,
-                                               arrived, eta)
-                trust = trust.at[drop_idx].set(t_new, mode="drop")
-            throttle = state.throttle
-            if throttle is not None:
-                th_new = stages.adaptive_throttle_update(
-                    thr_c, rej & arrived, arrived, adapt_scale)
-                throttle = throttle.at[drop_idx].set(th_new, mode="drop")
+                # trust EWMA / adaptive-attacker throttle, from this
+                # window's verdicts (only arrived slots were judged;
+                # churned slots keep their scores — trust_update's `seen`
+                # mask is the identity for them, so the proc-indexed
+                # scatter is harmless)
+                trust = state.trust
+                if trust is not None:
+                    t_new = detection.trust_update(trust_c, arrived & ~rej,
+                                                   arrived, eta)
+                    trust = trust.at[drop_idx].set(t_new, mode="drop")
+                throttle = state.throttle
+                if throttle is not None:
+                    th_new = stages.adaptive_throttle_update(
+                        thr_c, rej & arrived, arrived, adapt_scale)
+                    throttle = throttle.at[drop_idx].set(th_new,
+                                                         mode="drop")
 
             new_state = dataclasses.replace(
                 state, residuals=residuals, chain_key=chain_key,
@@ -550,78 +565,95 @@ class AsyncFleetEngine(MeshStateIO):
                         count, trust, throttle, x, y, sizes, order, proc,
                         avail, up_s, cx, cy):
             # 1. cohort gather: node-sharded -> replicated (C, ...) rows
-            t_arr = mesh_lib.gather_rows(next_arrival, order, axis, b)
-            vdisp_c = mesh_lib.gather_rows(dispatched_version, order,
-                                           axis, b)
-            disp_c = mesh_lib.gather_rows_tree(dispatched, order, axis, b)
-            res_c = mesh_lib.gather_rows_tree(residuals, order, axis, b)
-            xg = mesh_lib.gather_rows(x, order, axis, b)
-            yg = mesh_lib.gather_rows(y, order, axis, b)
-            sz = mesh_lib.gather_rows(sizes, order, axis, b)
+            with jax.named_scope(stages.FOLD):
+                t_arr = mesh_lib.gather_rows(next_arrival, order, axis, b)
+                vdisp_c = mesh_lib.gather_rows(dispatched_version, order,
+                                               axis, b)
+            with jax.named_scope(stages.LOCAL_SGD):
+                disp_c = mesh_lib.gather_rows_tree(dispatched, order, axis,
+                                                   b)
+            with jax.named_scope(stages.UPLOAD):
+                res_c = mesh_lib.gather_rows_tree(residuals, order, axis, b)
+            with jax.named_scope(stages.LOCAL_SGD):
+                xg = mesh_lib.gather_rows(x, order, axis, b)
+                yg = mesh_lib.gather_rows(y, order, axis, b)
+                sz = mesh_lib.gather_rows(sizes, order, axis, b)
 
-            if cfg.key_mode == "sequential":
-                chain_key, k1s, k2s = chain_node_keys_masked(chain_key, proc)
-            else:
-                chain_key, k1s, k2s = parallel_node_keys(chain_key,
-                                                         order.shape[0])
+                if cfg.key_mode == "sequential":
+                    chain_key, k1s, k2s = chain_node_keys_masked(chain_key,
+                                                                 proc)
+                else:
+                    chain_key, k1s, k2s = parallel_node_keys(
+                        chain_key, order.shape[0])
 
-            # 2. this device's cohort block through the upload pipeline
-            blk = lambda t: mesh_lib.my_block_tree(t, axis, d)
-            disp_b, res_b = blk(disp_c), blk(res_c)
-            local = jax.vmap(local_train)(disp_b, blk(xg), blk(yg), blk(sz),
-                                          blk(k1s))
-            deltas = jax.tree.map(lambda l, dd: l - dd.astype(l.dtype),
-                                  local, disp_b)
-            thr_c = (mesh_lib.gather_rows(throttle, order, axis, b)
-                     if throttle is not None else None)
-            if attack_stage is not None:
-                # shard-oblivious per-node row scaling on this device's
-                # cohort block (mal_full closes over as a replicated const)
-                mal_b = mesh_lib.my_block(jnp.take(mal_full, order), axis, d)
-                thr_b = (mesh_lib.my_block(thr_c, axis, d)
-                         if thr_c is not None else None)
-                deltas = attack_stage(deltas, mal_b, thr_b)
-            deltas, res_b, nnz_b = stages.upload_pipeline(
-                cfg, deltas, res_b, blk(k2s), need_nnz=need_nnz)
-            omegas_b, accs_b = stages.rebuild_and_evaluate(
-                raw_acc_fn, disp_b, deltas, cx, cy)
+                # 2. this device's cohort block through the upload pipeline
+                blk = lambda t: mesh_lib.my_block_tree(t, axis, d)
+                disp_b = blk(disp_c)
+                local = jax.vmap(local_train)(disp_b, blk(xg), blk(yg),
+                                              blk(sz), blk(k1s))
+                deltas = jax.tree.map(lambda l, dd: l - dd.astype(l.dtype),
+                                      local, disp_b)
+            with jax.named_scope(stages.UPLOAD):
+                res_b = blk(res_c)
+                thr_c = (mesh_lib.gather_rows(throttle, order, axis, b)
+                         if throttle is not None else None)
+                if attack_stage is not None:
+                    # shard-oblivious per-node row scaling on this device's
+                    # cohort block (mal_full closes over as a replicated
+                    # const)
+                    mal_b = mesh_lib.my_block(jnp.take(mal_full, order),
+                                              axis, d)
+                    thr_b = (mesh_lib.my_block(thr_c, axis, d)
+                             if thr_c is not None else None)
+                    deltas = attack_stage(deltas, mal_b, thr_b)
+                deltas, res_b, nnz_b = stages.upload_pipeline(
+                    cfg, deltas, res_b, blk(k2s), need_nnz=need_nnz)
+            with jax.named_scope(stages.CLOUD_SCORE):
+                omegas_b, accs_b = stages.rebuild_and_evaluate(
+                    raw_acc_fn, disp_b, deltas, cx, cy)
 
             # 3. gather the arrival set; fold replicated
-            omegas = mesh_lib.all_gather_tree(omegas_b, axis)
-            accs = jax.lax.all_gather(accs_b, axis, tiled=True)
-            res_c = mesh_lib.all_gather_tree(res_b, axis)
+            with jax.named_scope(stages.FOLD):
+                omegas = mesh_lib.all_gather_tree(omegas_b, axis)
+                accs = jax.lax.all_gather(accs_b, axis, tiled=True)
+            with jax.named_scope(stages.UPLOAD):
+                res_c = mesh_lib.all_gather_tree(res_b, axis)
 
             arrived = proc & avail
-            # the cohort trust rows are gathered replicated, so the fold's
-            # trust-weighted mixing stays identical on every device
-            trust_c = (mesh_lib.gather_rows(trust, order, axis, b)
-                       if trust is not None else None)
-            fold = (sequential_fold if cfg.mixing == "sequential"
-                    else buffered_fold)
-            params, version, ring, count, p_seq, v_seq, rej, taus, aud = \
-                fold(params, version, ring, count, omegas, accs, vdisp_c,
-                     arrived, trust_c=trust_c)
+            with jax.named_scope(stages.FOLD):
+                # the cohort trust rows are gathered replicated, so the
+                # fold's trust-weighted mixing stays identical on every
+                # device
+                trust_c = (mesh_lib.gather_rows(trust, order, axis, b)
+                           if trust is not None else None)
+                fold = (sequential_fold if cfg.mixing == "sequential"
+                        else buffered_fold)
+                params, version, ring, count, p_seq, v_seq, rej, taus, aud \
+                    = fold(params, version, ring, count, omegas, accs,
+                           vdisp_c, arrived, trust_c=trust_c)
 
-            # 4. redispatch: scatter processed rows back to their owners
-            dispatched = mesh_lib.scatter_rows_tree(dispatched, order, p_seq,
-                                                    proc, axis, b)
-            residuals = mesh_lib.scatter_rows_tree(residuals, order, res_c,
-                                                   proc, axis, b)
-            dispatched_version = mesh_lib.scatter_rows(
-                dispatched_version, order, v_seq, proc, axis, b)
-            t_next = t_arr + up_s + jnp.take(comp_s, order)
-            next_arrival = mesh_lib.scatter_rows(next_arrival, order, t_next,
-                                                 proc, axis, b)
-            if trust is not None:
-                t_new = detection.trust_update(trust_c, arrived & ~rej,
-                                               arrived, eta)
-                trust = mesh_lib.scatter_rows(trust, order, t_new, proc,
-                                              axis, b)
-            if throttle is not None:
-                th_new = stages.adaptive_throttle_update(
-                    thr_c, rej & arrived, arrived, adapt_scale)
-                throttle = mesh_lib.scatter_rows(throttle, order, th_new,
-                                                 proc, axis, b)
+                # 4. redispatch: scatter processed rows back to their owners
+                dispatched = mesh_lib.scatter_rows_tree(dispatched, order,
+                                                        p_seq, proc, axis, b)
+            with jax.named_scope(stages.UPLOAD):
+                residuals = mesh_lib.scatter_rows_tree(residuals, order,
+                                                       res_c, proc, axis, b)
+            with jax.named_scope(stages.FOLD):
+                dispatched_version = mesh_lib.scatter_rows(
+                    dispatched_version, order, v_seq, proc, axis, b)
+                t_next = t_arr + up_s + jnp.take(comp_s, order)
+                next_arrival = mesh_lib.scatter_rows(next_arrival, order,
+                                                     t_next, proc, axis, b)
+                if trust is not None:
+                    t_new = detection.trust_update(trust_c, arrived & ~rej,
+                                                   arrived, eta)
+                    trust = mesh_lib.scatter_rows(trust, order, t_new, proc,
+                                                  axis, b)
+                if throttle is not None:
+                    th_new = stages.adaptive_throttle_update(
+                        thr_c, rej & arrived, arrived, adapt_scale)
+                    throttle = mesh_lib.scatter_rows(throttle, order,
+                                                     th_new, proc, axis, b)
             metrics = {
                 "n_rejected": (rej & arrived).sum(),
                 "max_staleness": jnp.where(arrived, taus, 0).max(),
@@ -686,8 +718,15 @@ class AsyncFleetEngine(MeshStateIO):
         arrivals) avoid a test forward pass + device sync per window."""
         tr = self.obs
         w = self._window_idx
-        span = tr.span("window", window=w)
-        span.__enter__()
+        with host_span(tr, "window", window=w) as span:
+            rec, t_first = self._window(tr, w, max_arrivals, evaluate)
+            span.set(n_processed=rec.n_processed, n_rejected=rec.n_rejected,
+                     version=rec.version)
+            span.set_virtual(t_first, rec.t)
+        return rec
+
+    def _window(self, tr, w: int, max_arrivals: Optional[int],
+                evaluate: bool) -> Tuple[AsyncWindowRecord, float]:
         with timed_stage(tr, "window.select", window=w):
             order, proc = self.select_window(max_arrivals)
         t_arr = np.asarray(self.state.next_arrival, np.float64)[order]
@@ -717,33 +756,31 @@ class AsyncFleetEngine(MeshStateIO):
             up_host = self._comm_pad32[order].astype(np.float64)
         up_s = jnp.asarray(up_host, jnp.float32)
 
-        dev = timed_stage(tr, "window.device", window=w)
-        dev.__enter__()
-        if self.mesh is not None:
-            st = self.state
-            (self.params, residuals, chain_key, dispatched, next_arrival,
-             dispatched_version, version, ring, count, trust, throttle,
-             m) = self._window_fn(
-                self.params, st.residuals, st.chain_key, st.dispatched,
-                st.next_arrival, st.dispatched_version, st.version,
-                st.acc_ring, st.acc_count, st.trust, st.throttle,
-                self.data.x, self.data.y,
-                self.data.sizes, jnp.asarray(order, jnp.int32),
-                jnp.asarray(proc), jnp.asarray(avail), up_s,
-                *self.cloud_test)
-            self.state = dataclasses.replace(
-                st, residuals=residuals, chain_key=chain_key,
-                dispatched=dispatched, next_arrival=next_arrival,
-                dispatched_version=dispatched_version, version=version,
-                acc_ring=ring, acc_count=count, trust=trust,
-                throttle=throttle)
-        else:
-            self.params, self.state, m = self._window_fn(
-                self.params, self.state, self.data.x, self.data.y,
-                self.data.sizes, jnp.asarray(order, jnp.int32),
-                jnp.asarray(proc), jnp.asarray(avail), up_s)
-        dev.fence((self.params, m))
-        dev.__exit__(None, None, None)
+        with timed_stage(tr, "window.device", window=w) as dev:
+            if self.mesh is not None:
+                st = self.state
+                (self.params, residuals, chain_key, dispatched, next_arrival,
+                 dispatched_version, version, ring, count, trust, throttle,
+                 m) = self._window_fn(
+                    self.params, st.residuals, st.chain_key, st.dispatched,
+                    st.next_arrival, st.dispatched_version, st.version,
+                    st.acc_ring, st.acc_count, st.trust, st.throttle,
+                    self.data.x, self.data.y,
+                    self.data.sizes, jnp.asarray(order, jnp.int32),
+                    jnp.asarray(proc), jnp.asarray(avail), up_s,
+                    *self.cloud_test)
+                self.state = dataclasses.replace(
+                    st, residuals=residuals, chain_key=chain_key,
+                    dispatched=dispatched, next_arrival=next_arrival,
+                    dispatched_version=dispatched_version, version=version,
+                    acc_ring=ring, acc_count=count, trust=trust,
+                    throttle=throttle)
+            else:
+                self.params, self.state, m = self._window_fn(
+                    self.params, self.state, self.data.x, self.data.y,
+                    self.data.sizes, jnp.asarray(order, jnp.int32),
+                    jnp.asarray(proc), jnp.asarray(avail), up_s)
+            dev.fence((self.params, m))
         self._window_idx = w + 1
 
         # host-side clock/traffic accounting over the processed arrivals.
@@ -781,11 +818,7 @@ class AsyncFleetEngine(MeshStateIO):
         self.history.append(rec)
         if tr.enabled:
             self._emit_window_events(rec, sel, proc, avail, t_arrive, m)
-        span.set(n_processed=rec.n_processed, n_rejected=rec.n_rejected,
-                 version=rec.version)
-        span.set_virtual(float(t_arr[0]) if t_arr.size else 0.0, rec.t)
-        span.__exit__(None, None, None)
-        return rec
+        return rec, float(t_arr[0]) if t_arr.size else 0.0
 
     def _emit_window_events(self, rec: AsyncWindowRecord, sel, proc, avail,
                             t_arrive, m) -> None:

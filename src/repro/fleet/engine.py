@@ -37,7 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import async_update, detection
-from ..obs import WINDOW_SIZE_EDGES, get_tracer, timed_stage
+from ..obs import (WINDOW_SIZE_EDGES, get_tracer, host_span,
+                   timed_stage)
 from . import mesh as mesh_lib
 from . import stages
 from .mesh import FleetMesh, MeshStateIO
@@ -236,7 +237,9 @@ class FleetEngine(MeshStateIO):
         self.obs = tracer if tracer is not None else get_tracer()
         self.params = init_params
         self.loss_fn = loss_fn
-        self.acc_fn = jax.jit(acc_fn)
+        # the test-set pass's scope; inside the round program, which also
+        # scores the uploads with it, those ops count to the cloud score
+        self.acc_fn = jax.jit(stages.scoped(stages.EVALUATE, acc_fn))
         (self.data, self.n_nodes, self.test_data, self.cloud_test,
          self.profile, self.n_params) = stages.init_engine_common(
             init_params, node_data, test_data, cloud_test, profile)
@@ -288,61 +291,71 @@ class FleetEngine(MeshStateIO):
         def round_fn(params, residuals, chain_key, trust, throttle,
                      x, y, sizes, idx, valid):
             c = idx.shape[0]
-            xg = jnp.take(x, idx, axis=0)
-            yg = jnp.take(y, idx, axis=0)
-            sz = jnp.take(sizes, idx, axis=0)
-            res_c = gather_nodes(residuals, idx)
+            with jax.named_scope(stages.LOCAL_SGD):
+                xg = jnp.take(x, idx, axis=0)
+                yg = jnp.take(y, idx, axis=0)
+                sz = jnp.take(sizes, idx, axis=0)
+            with jax.named_scope(stages.UPLOAD):
+                res_c = gather_nodes(residuals, idx)
 
-            if cfg.key_mode == "sequential":
-                chain_key, k1s, k2s = chain_node_keys(chain_key, c)
-            else:
-                chain_key, k1s, k2s = parallel_node_keys(chain_key, c)
+            with jax.named_scope(stages.LOCAL_SGD):
+                if cfg.key_mode == "sequential":
+                    chain_key, k1s, k2s = chain_node_keys(chain_key, c)
+                else:
+                    chain_key, k1s, k2s = parallel_node_keys(chain_key, c)
 
-            local = jax.vmap(local_train, in_axes=(None, 0, 0, 0, 0))(
-                params, xg, yg, sz, k1s)
-            deltas = jax.tree.map(lambda l, g: l - g[None].astype(l.dtype),
-                                  local, params)
-            if attack_stage is not None:
-                mal_c = jnp.take(mal_full, idx)
-                thr_c = (jnp.take(throttle, idx)
-                         if throttle is not None else None)
-                deltas = attack_stage(deltas, mal_c, thr_c)
-            deltas, res_c, nnz = stages.upload_pipeline(cfg, deltas, res_c,
-                                                        k2s,
-                                                        need_nnz=need_nnz)
+                local = jax.vmap(local_train, in_axes=(None, 0, 0, 0, 0))(
+                    params, xg, yg, sz, k1s)
+                deltas = jax.tree.map(
+                    lambda l, g: l - g[None].astype(l.dtype), local, params)
+            with jax.named_scope(stages.UPLOAD):
+                if attack_stage is not None:
+                    mal_c = jnp.take(mal_full, idx)
+                    thr_c = (jnp.take(throttle, idx)
+                             if throttle is not None else None)
+                    deltas = attack_stage(deltas, mal_c, thr_c)
+                deltas, res_c, nnz = stages.upload_pipeline(
+                    cfg, deltas, res_c, k2s, need_nnz=need_nnz)
 
             # cloud side: rebuild node models, test, detect, aggregate, mix
-            omegas, accs = stages.rebuild_and_evaluate(
-                raw_acc_fn, params, deltas, cloud_x, cloud_y)
-            if cfg.detect:
-                mask, thr = detect_masked(accs, valid, cfg.detect_s)
-            else:
-                mask, thr = valid, jnp.zeros((), jnp.float32)
-            if trust is not None:
-                trust_c = jnp.take(trust, idx)
-                w = detection.trust_weights(
-                    trust_c, accs, mask, cfg.trust_floor,
-                    cfg.uncertainty_scale)
-                omega_mean = detection.masked_weighted_mean(omegas, mask, w)
-            else:
-                omega_mean = detection.masked_mean(omegas, mask)
-            new_params = async_update.mix(params, omega_mean, cfg.alpha)
+            with jax.named_scope(stages.CLOUD_SCORE):
+                omegas, accs = stages.rebuild_and_evaluate(
+                    raw_acc_fn, params, deltas, cloud_x, cloud_y)
+                if cfg.detect:
+                    mask, thr = detect_masked(accs, valid, cfg.detect_s)
+                else:
+                    mask, thr = valid, jnp.zeros((), jnp.float32)
+            with jax.named_scope(stages.FOLD):
+                if trust is not None:
+                    trust_c = jnp.take(trust, idx)
+                    w = detection.trust_weights(
+                        trust_c, accs, mask, cfg.trust_floor,
+                        cfg.uncertainty_scale)
+                    omega_mean = detection.masked_weighted_mean(omegas,
+                                                                mask, w)
+                else:
+                    omega_mean = detection.masked_mean(omegas, mask)
+                new_params = async_update.mix(params, omega_mean, cfg.alpha)
 
             # write cohort residuals back; padded slots scatter out of bounds
             # and are dropped
             drop_idx = jnp.where(valid, idx, self.n_nodes)
-            residuals = jax.tree.map(
-                lambda full, part: full.at[drop_idx].set(part, mode="drop"),
-                residuals, res_c)
-            if trust is not None:
-                trust_c = detection.trust_update(
-                    jnp.take(trust, idx), mask, valid, cfg.trust_eta)
-                trust = trust.at[drop_idx].set(trust_c, mode="drop")
-            if throttle is not None:
-                thr_new = stages.adaptive_throttle_update(
-                    jnp.take(throttle, idx), valid & ~mask, valid,
-                    self.attack.adapt_poison_scale)
-                throttle = throttle.at[drop_idx].set(thr_new, mode="drop")
+            with jax.named_scope(stages.UPLOAD):
+                residuals = jax.tree.map(
+                    lambda full, part: full.at[drop_idx].set(part,
+                                                             mode="drop"),
+                    residuals, res_c)
+            with jax.named_scope(stages.FOLD):
+                if trust is not None:
+                    trust_c = detection.trust_update(
+                        jnp.take(trust, idx), mask, valid, cfg.trust_eta)
+                    trust = trust.at[drop_idx].set(trust_c, mode="drop")
+                if throttle is not None:
+                    thr_new = stages.adaptive_throttle_update(
+                        jnp.take(throttle, idx), valid & ~mask, valid,
+                        self.attack.adapt_poison_scale)
+                    throttle = throttle.at[drop_idx].set(thr_new,
+                                                         mode="drop")
             m = {"accs": accs, "mask": mask, "thr": thr}
             if need_nnz:
                 m["nnz"] = nnz
@@ -382,74 +395,82 @@ class FleetEngine(MeshStateIO):
             # both modes yield the exact per-node streams the single-device
             # engine draws for an arange cohort (padding rows reuse the last
             # real key for their masked-out dummy updates)
-            if cfg.key_mode == "sequential":
-                chain_key, k1s, k2s = chain_node_keys(chain_key, n)
-            else:
-                chain_key, k1s, k2s = parallel_node_keys(chain_key, n)
-            k1s, k2s = pad_keys(k1s, n_pad), pad_keys(k2s, n_pad)
-            k1 = mesh_lib.my_block(k1s, axis, d)
-            k2 = mesh_lib.my_block(k2s, axis, d)
+            with jax.named_scope(stages.LOCAL_SGD):
+                if cfg.key_mode == "sequential":
+                    chain_key, k1s, k2s = chain_node_keys(chain_key, n)
+                else:
+                    chain_key, k1s, k2s = parallel_node_keys(chain_key, n)
+                k1s, k2s = pad_keys(k1s, n_pad), pad_keys(k2s, n_pad)
+                k1 = mesh_lib.my_block(k1s, axis, d)
+                k2 = mesh_lib.my_block(k2s, axis, d)
 
-            local = jax.vmap(local_train, in_axes=(None, 0, 0, 0, 0))(
-                params, x, y, sizes, k1)
-            deltas = jax.tree.map(lambda l, g: l - g[None].astype(l.dtype),
-                                  local, params)
-            if attack_stage is not None:
-                # the attack stage is shard-oblivious: per-node row scaling
-                # on this device's block of the (replicated) malicious mask
-                mal_blk = mesh_lib.my_block(mal_full, axis, d)
-                deltas = attack_stage(deltas, mal_blk, throttle)
-            deltas, res_new, nnz = stages.upload_pipeline(
-                cfg, deltas, residuals, k2, need_nnz=need_nnz)
-            omegas, accs = stages.rebuild_and_evaluate(
-                raw_acc_fn, params, deltas, cx, cy)
+                local = jax.vmap(local_train, in_axes=(None, 0, 0, 0, 0))(
+                    params, x, y, sizes, k1)
+                deltas = jax.tree.map(
+                    lambda l, g: l - g[None].astype(l.dtype), local, params)
+            with jax.named_scope(stages.UPLOAD):
+                if attack_stage is not None:
+                    # the attack stage is shard-oblivious: per-node row
+                    # scaling on this device's block of the (replicated)
+                    # malicious mask
+                    mal_blk = mesh_lib.my_block(mal_full, axis, d)
+                    deltas = attack_stage(deltas, mal_blk, throttle)
+                deltas, res_new, nnz = stages.upload_pipeline(
+                    cfg, deltas, residuals, k2, need_nnz=need_nnz)
+            with jax.named_scope(stages.CLOUD_SCORE):
+                omegas, accs = stages.rebuild_and_evaluate(
+                    raw_acc_fn, params, deltas, cx, cy)
 
-            # cloud side, replicated: global accuracy set -> Alg. 2 mask
-            accs_all = jax.lax.all_gather(accs, axis, tiled=True)
-            valid_all = jax.lax.all_gather(valid, axis, tiled=True)
-            if cfg.detect:
-                mask_all, thr = detect_masked(accs_all, valid_all,
-                                              cfg.detect_s)
-            else:
-                mask_all, thr = valid_all, jnp.zeros((), jnp.float32)
-            mask = mesh_lib.my_block(mask_all, axis, d)
+                # cloud side, replicated: global accuracy set -> Alg. 2 mask
+                accs_all = jax.lax.all_gather(accs, axis, tiled=True)
+                valid_all = jax.lax.all_gather(valid, axis, tiled=True)
+                if cfg.detect:
+                    mask_all, thr = detect_masked(accs_all, valid_all,
+                                                  cfg.detect_s)
+                else:
+                    mask_all, thr = valid_all, jnp.zeros((), jnp.float32)
+                mask = mesh_lib.my_block(mask_all, axis, d)
 
-            if trust is not None:
-                # trust/uncertainty weights against the globally-reduced
-                # accepted-mean accuracy (every shard shares the anchor)
-                m_all = mask_all.astype(jnp.float32)
-                ref = ((accs_all.astype(jnp.float32) * m_all).sum()
-                       / jnp.maximum(m_all.sum(), 1.0))
-                w = mask.astype(jnp.float32) * detection.trust_weights(
-                    trust, accs, mask, cfg.trust_floor,
-                    cfg.uncertainty_scale, ref=ref)
-                total = jax.lax.psum(w.sum(), axis)
-                denom = jnp.where(total > 0, total, 1.0)
-            else:
-                # masked mean: per-shard weighted partial sums + psum
-                w = mask.astype(jnp.float32)
-                denom = jnp.maximum(jax.lax.psum(w.sum(), axis), 1.0)
+            with jax.named_scope(stages.FOLD):
+                if trust is not None:
+                    # trust/uncertainty weights against the globally-reduced
+                    # accepted-mean accuracy (every shard shares the anchor)
+                    m_all = mask_all.astype(jnp.float32)
+                    ref = ((accs_all.astype(jnp.float32) * m_all).sum()
+                           / jnp.maximum(m_all.sum(), 1.0))
+                    w = mask.astype(jnp.float32) * detection.trust_weights(
+                        trust, accs, mask, cfg.trust_floor,
+                        cfg.uncertainty_scale, ref=ref)
+                    total = jax.lax.psum(w.sum(), axis)
+                    denom = jnp.where(total > 0, total, 1.0)
+                else:
+                    # masked mean: per-shard weighted partial sums + psum
+                    w = mask.astype(jnp.float32)
+                    denom = jnp.maximum(jax.lax.psum(w.sum(), axis), 1.0)
 
-            def agg(o):
-                wf = w.reshape((-1,) + (1,) * (o.ndim - 1))
-                return jax.lax.psum((o.astype(jnp.float32) * wf).sum(0),
-                                    axis) / denom
+                def agg(o):
+                    wf = w.reshape((-1,) + (1,) * (o.ndim - 1))
+                    return jax.lax.psum((o.astype(jnp.float32) * wf).sum(0),
+                                        axis) / denom
 
-            omega_mean = jax.tree.map(agg, omegas)
-            new_params = async_update.mix(params, omega_mean, cfg.alpha)
+                omega_mean = jax.tree.map(agg, omegas)
+                new_params = async_update.mix(params, omega_mean, cfg.alpha)
 
             # participants' residuals advance; everyone else's stay put
-            residuals = jax.tree.map(
-                lambda old, new: jnp.where(
-                    valid.reshape((-1,) + (1,) * (old.ndim - 1)), new, old),
-                residuals, res_new)
-            if trust is not None:
-                trust = detection.trust_update(trust, mask, valid,
-                                               cfg.trust_eta)
-            if throttle is not None:
-                throttle = stages.adaptive_throttle_update(
-                    throttle, valid & ~mask, valid,
-                    self.attack.adapt_poison_scale)
+            with jax.named_scope(stages.UPLOAD):
+                residuals = jax.tree.map(
+                    lambda old, new: jnp.where(
+                        valid.reshape((-1,) + (1,) * (old.ndim - 1)), new,
+                        old),
+                    residuals, res_new)
+            with jax.named_scope(stages.FOLD):
+                if trust is not None:
+                    trust = detection.trust_update(trust, mask, valid,
+                                                   cfg.trust_eta)
+                if throttle is not None:
+                    throttle = stages.adaptive_throttle_update(
+                        throttle, valid & ~mask, valid,
+                        self.attack.adapt_poison_scale)
             m = {"accs": accs_all, "mask": mask_all, "thr": thr}
             if need_nnz:
                 m["nnz"] = jax.lax.all_gather(nnz, axis, tiled=True)
@@ -465,12 +486,24 @@ class FleetEngine(MeshStateIO):
             out_specs=(pr, pn, pr, pn, pn, m_specs))
 
     # -- host-side driver ---------------------------------------------------
-    def run_round(self) -> FleetRoundRecord:
-        cfg = self.cfg
+    def run_round(self, on_record: Optional[Callable[
+            [FleetRoundRecord], None]] = None) -> FleetRoundRecord:
+        """One barrier round.  ``on_record`` is called with the new record
+        before the round's span closes, so host work a caller does per
+        record (the api steppers' privacy accountant) nests in it."""
         tr = self.obs
         r = self.state.round
-        span = tr.span("round", round=r)
-        span.__enter__()
+        t_prev = self.history[-1].t if self.history else self._t0
+        with host_span(tr, "round", round=r) as span:
+            rec = self._round(tr, r, t_prev)
+            if on_record is not None:
+                on_record(rec)
+            span.set(n_participating=rec.n_participating,
+                     n_rejected=rec.n_rejected)
+            span.set_virtual(t_prev, rec.t)
+        return rec
+
+    def _round(self, tr, r: int, t_prev: float) -> FleetRoundRecord:
         idx, valid = self.sampler.cohort(r, self.n_nodes)
         with timed_stage(tr, "round.device", round=r) as st:
             if self.mesh is not None:
@@ -492,16 +525,14 @@ class FleetEngine(MeshStateIO):
         self.state = FleetState(residuals=residuals, chain_key=chain_key,
                                 round=r + 1, trust=trust, throttle=throttle)
 
+        with timed_stage(tr, "round.readback", round=r):
+            mask = np.asarray(m["mask"])
+            nnz = np.asarray(m["nnz"]) if self.net is not None else None
         n_part = int(valid.sum())
         if self.mesh is not None:   # sharded mask is per-node over n_pad
-            n_rejected = int((up & ~np.asarray(m["mask"])).sum())
+            n_rejected = int((up & ~mask).sum())
         else:
-            n_rejected = int((np.asarray(valid)
-                              & ~np.asarray(m["mask"])).sum())
-        bpn = self.bytes_per_node()
-        comp, comm = self.profile.round_times(np.asarray(idx),
-                                              np.asarray(valid), bpn)
-        comm_bytes = bpn * n_part
+            n_rejected = int((np.asarray(valid) & ~mask).sum())
         if self.net is not None:
             # byte-accurate path: the round's measured nonzero counts price
             # each participant's upload through the wire codec; the link
@@ -509,33 +540,35 @@ class FleetEngine(MeshStateIO):
             # (parallel uploads — the barrier waits on the slowest)
             if self.mesh is not None:       # nnz is per-node over n_pad
                 sel_nodes = np.flatnonzero(up[:self.n_nodes])
-                nnz_sel = np.asarray(m["nnz"])[sel_nodes]
+                nnz_sel = nnz[sel_nodes]
             else:                           # nnz is in cohort (idx) order
                 valid_np = np.asarray(valid)
                 sel_nodes = np.asarray(idx)[valid_np]
-                nnz_sel = np.asarray(m["nnz"])[valid_np]
+                nnz_sel = nnz[valid_np]
             flood = self.attack.flood_uploads if self.attack else 0
-            with timed_stage(tr, "net.draw", round=r) as st:
+            with timed_stage(tr, "net.draw", round=r):
                 draw = self.net.draw(sel_nodes, extra_concurrency=flood)
-            with timed_stage(tr, "net.commit", round=r) as st:
+            with timed_stage(tr, "net.commit", round=r):
                 enc = self.net.commit(draw, nnz_sel, ctx={"round": r})
-            comm = float(draw.transfer_s.max()) if sel_nodes.size else 0.0
-            comm_bytes = float(enc.sum())
-        t_prev = self.history[-1].t if self.history else self._t0
-        with timed_stage(tr, "round.evaluate", round=r) as st:
+        with timed_stage(tr, "round.evaluate", round=r):
             accuracy = self.global_accuracy()
-        rec = FleetRoundRecord(
-            t=t_prev + comp + comm, round=r,
-            accuracy=accuracy, comm_bytes=comm_bytes,
-            comp_time=comp, comm_time=comm, n_participating=n_part,
-            n_rejected=n_rejected)
-        self.history.append(rec)
+        with timed_stage(tr, "round.account", round=r):
+            bpn = self.bytes_per_node()
+            comp, comm = self.profile.round_times(np.asarray(idx),
+                                                  np.asarray(valid), bpn)
+            comm_bytes = bpn * n_part
+            if self.net is not None:
+                comm = float(draw.transfer_s.max()) if sel_nodes.size else 0.0
+                comm_bytes = float(enc.sum())
+            rec = FleetRoundRecord(
+                t=t_prev + comp + comm, round=r,
+                accuracy=accuracy, comm_bytes=comm_bytes,
+                comp_time=comp, comm_time=comm, n_participating=n_part,
+                n_rejected=n_rejected)
+            self.history.append(rec)
         if tr.enabled:
             self._emit_round_events(rec, idx, valid, m, up if self.mesh
                                     is not None else None)
-        span.set(n_participating=n_part, n_rejected=n_rejected)
-        span.set_virtual(t_prev, rec.t)
-        span.__exit__(None, None, None)
         return rec
 
     def _emit_round_events(self, rec: FleetRoundRecord, idx, valid, m,

@@ -36,6 +36,27 @@ import numpy as np
 from ..core import accumulator as accum
 from ..core import aldp, detection
 
+# The named scopes of a round's (or window's) stages.  The engines wrap
+# each stage's ops in its scope, so every HLO op it lowers to carries the
+# scope in its `op_name` metadata and a profile's device ops can be put to
+# the stage that issued them.  Scopes change metadata only, never the ops.
+LOCAL_SGD = "fleet.local_sgd"       # cohort gather, local SGD, deltas
+UPLOAD = "fleet.upload"             # attack, DGC + ALDP, residual scatter
+CLOUD_SCORE = "fleet.cloud_score"   # rebuild + cloud accuracy, Alg. 2
+FOLD = "fleet.fold"                 # aggregation / window fold, mix, trust
+EVALUATE = "fleet.evaluate"         # the global model's test-set pass
+
+
+def scoped(name: str, fn):
+    """``fn`` with every op it traces under the named scope ``name``."""
+
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+
+    return run
+
 
 # ---------------------------------------------------------------------------
 # stage: node-local minibatch SGD

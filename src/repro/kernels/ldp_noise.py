@@ -109,6 +109,7 @@ def ldp_perturb_flat(flat: jnp.ndarray, seed: jnp.ndarray,
         out_specs=pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, flat.dtype),
         interpret=interpret_mode(interpret),
+        name="ldp_noise",
     )(seed.reshape(1).astype(jnp.int32), clip_scale.reshape(1).astype(jnp.float32), x)
     return out.reshape(-1)[:n]
 
@@ -141,5 +142,6 @@ def ldp_perturb_fleet(flat: jnp.ndarray, seeds: jnp.ndarray,
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct(x.shape, flat.dtype),
         interpret=interpret_mode(interpret),
+        name="ldp_noise",
     )(seeds.astype(jnp.int32), clip_scales.astype(jnp.float32), x)
     return out.reshape(k, -1)[:, :n]

@@ -51,6 +51,7 @@ def sparsify_flat(grad: jnp.ndarray, residual: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct(g.shape, grad.dtype),
                    jax.ShapeDtypeStruct(g.shape, residual.dtype)],
         interpret=interpret_mode(interpret),
+        name="sparsify",
     )(threshold.reshape(1).astype(jnp.float32), g, r)
     return up.reshape(-1)[:n], newr.reshape(-1)[:n]
 
@@ -78,5 +79,6 @@ def sparsify_fleet(grads: jnp.ndarray, residuals: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct(g.shape, grads.dtype),
                    jax.ShapeDtypeStruct(g.shape, residuals.dtype)],
         interpret=interpret_mode(interpret),
+        name="sparsify",
     )(thresholds.astype(jnp.float32), g, r)
     return (up.reshape(k, -1)[:, :n], newr.reshape(k, -1)[:, :n])

@@ -167,7 +167,8 @@ def upload_fused_fleet(flat: jnp.ndarray,
         boundaries=tuple(int(b) for b in boundaries))
     outs = pl.pallas_call(
         kernel, grid=(k, nb), in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret_mode(interpret))(*args)
+        out_shape=out_shape, interpret=interpret_mode(interpret),
+        name="upload_fused")(*args)
     outs = list(outs)
     up = outs.pop(0).reshape(k, -1)[:, :n]
     newr = outs.pop(0).reshape(k, -1)[:, :n] if do_sparsify else None

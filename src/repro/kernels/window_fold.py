@@ -81,6 +81,7 @@ def window_fold_fleet(p_flat: jnp.ndarray, om_flat: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct(om.shape, jnp.float32),
                    jax.ShapeDtypeStruct(p.shape, jnp.float32)],
         interpret=interpret_mode(interpret),
+        name="window_fold",
     )(gates.astype(jnp.int32), a.astype(jnp.float32),
       b.astype(jnp.float32), p, om)
     return final.reshape(-1)[:n], seq.reshape(c, -1)[:, :n]

@@ -68,5 +68,6 @@ def nnz_fleet(flat: jnp.ndarray, *, block_rows: int = 256,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret_mode(interpret),
+        name="wire_bytes",
     )(g)
     return out[:, 0, 0]

@@ -11,8 +11,9 @@ The observability layer under the whole fleet/net/kernel stack:
   * `sinks`   — crash-safe streaming JSONL (`JsonlWriter`/`JsonlSink` +
     `read_jsonl`), `MemorySink` for tests, and the Chrome-trace/Perfetto
     exporter (`chrome_trace`/`write_chrome_trace`);
-  * `timers`  — `block_until_ready`-fenced per-stage timing
-    (`timed_stage`) and the kernel profiling primitive (`bench_kernel`);
+  * `timers`  — host stage spans on the profiler's clock (`host_span`,
+    `timed_stage`, fenced only under ``stage_timings``), ``py.gc`` spans
+    (`GcSpans`) and the kernel profiling primitive (`bench_kernel`);
   * `analysis` — `FleetAnalytics`, the streaming trace-analytics sink
     folding arrival/window/upload/verdict events into derived fleet
     indicators (straggler scores, occupancy/skew, byte accounting,
@@ -25,7 +26,10 @@ The observability layer under the whole fleet/net/kernel stack:
 
 Enabled per experiment through `api.ObsSpec`; with the spec at its
 default (off) no event is constructed and the engines' jitted programs
-are unchanged — tracing costs nothing until asked for.  `repro.obs`
+are unchanged — tracing costs nothing until asked for.  The stage spans'
+profiler annotations, and the engines' `jax.named_scope`s on the device
+side, are there in every run: they land in any `jax.profiler` trace and
+do nothing when no profile is being taken.  `repro.obs`
 imports nothing from the rest of the repo (and jax only lazily, for
 fencing), so every layer down to the kernels can depend on it.
 """
@@ -40,4 +44,5 @@ from .report import postmortem_md, run_diff_md  # noqa: F401
 from .sinks import (OBS_SCHEMA_VERSION, JsonlSink, JsonlWriter,  # noqa: F401
                     MemorySink, Sink, chrome_trace, read_events,
                     read_jsonl, write_chrome_trace)
-from .timers import bench_kernel, fence, timed_stage  # noqa: F401
+from .timers import (GcSpans, bench_kernel, fence,  # noqa: F401
+                     host_span, timed_stage)

@@ -1,33 +1,41 @@
-"""Host-side stage timers + the kernels profiling mode.
+"""Host stage spans on the profiler's clock, and the kernels profiling mode.
 
-JAX dispatch is asynchronous: `time.perf_counter` around a jitted call
-measures dispatch latency, not execution.  Everything here is
-`block_until_ready`-fenced:
+Every host stage of a record (the round or window, the device program's
+dispatch, the read-backs, net draw/commit, evaluation, the accountant)
+opens one `Stage`.  A stage is always a `jax.profiler.TraceAnnotation` of
+its name, so it lands in any `jax.profiler` trace on the same clock as
+the device's ops, with no `ObsSpec` at all; when no profile is being
+taken the annotation does nothing.  Two modes add to it:
 
-  * `timed_stage(tracer, name)` — a span context for one pipeline stage
-    (select_window, the device program, net draw/commit, evaluation).
-    The caller fences the stage's outputs via ``st.fence(out)`` before
-    the context exits, so the span's wall duration covers the device
-    work.  A disabled tracer yields a no-op context whose `fence` does
-    nothing — untimed runs keep JAX's async pipelining (fencing an
-    async dispatch chain would serialize it, which is itself a perf
-    change; that is why timing is opt-in per run, never ambient).
-  * `bench_kernel(name, fn, *args)` — the microbenchmark primitive
-    `benchmarks/kernels_micro.py` consumes: warmup + fenced timing loop,
-    µs/call, and a counter event + histogram sample into the tracer so a
-    profiling run of the kernel suite lands in the same trace/metrics
-    stream as everything else (the measurement harness the Pallas
-    upload-pipeline megakernel work will argue from).
+  * `host_span(tracer, name)` — also the `obs` span event ``name`` when
+    the tracer is enabled (the ``round`` and ``window`` spans the
+    analytics read);
+  * `timed_stage(tracer, name)` — the ``stage.<name>`` span, which is an
+    `obs` event and a fence only in the measurement mode
+    (``tracer.stage_timings``): the caller fences the stage's outputs via
+    ``st.fence(out)`` before the context exits, so the event's wall
+    duration covers the device work.  JAX dispatch is asynchronous, and
+    fencing serializes it, which is itself a perf change; that is why it
+    is opt-in per run, never ambient.  Outside that mode `fence` returns
+    its argument untouched.
+
+`GcSpans` puts Python's garbage collections on the same clock, as
+``py.gc`` spans.
+
+`bench_kernel(name, fn, *args)` is the microbenchmark primitive
+`benchmarks/kernels_micro.py` consumes: warmup + fenced timing loop,
+µs/call, and a counter event + histogram sample into the tracer.
 
 `fence` accepts any pytree (jax arrays, tuples, dicts) and tolerates
 plain host values, so call sites don't special-case output shapes.
 """
 from __future__ import annotations
 
+import gc
 import time
 from typing import Any, Optional
 
-from .events import Tracer, get_tracer
+from .events import _NULL_SPAN, Tracer, get_tracer
 from .metrics import SECONDS_EDGES
 
 
@@ -38,63 +46,88 @@ def fence(x: Any) -> Any:
     return jax.block_until_ready(x)
 
 
-class _TimedStage:
-    """Open stage timer: `fence` outputs inside, span emitted at exit."""
-    __slots__ = ("_span", "_tracer", "_name")
+def _annotation(name: str, **args):
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **args)
 
-    def __init__(self, tracer: Tracer, name: str, virt_t, tags):
-        self._tracer = tracer
-        self._name = name
-        self._span = tracer.span(f"stage.{name}", virt_t=virt_t, **tags)
+
+class Stage:
+    """One open host stage: the profiler annotation, the `obs` span (or
+    the null one) and whether `fence` blocks."""
+    __slots__ = ("_annotation", "_span", "_fenced")
+
+    def __init__(self, name: str, span, fenced: bool, tags):
+        self._annotation = _annotation(name, **tags)
+        self._span = span
+        self._fenced = fenced
 
     def fence(self, x: Any) -> Any:
-        return fence(x)
+        return fence(x) if self._fenced else x
 
     def set(self, **tags) -> None:
         self._span.set(**tags)
 
+    def set_virtual(self, virt_t=None, virt_end=None) -> None:
+        self._span.set_virtual(virt_t, virt_end)
+
     def __enter__(self):
+        self._annotation.__enter__()
         self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
-        out = self._span.__exit__(*exc)
-        return out
-
-
-class _NullStage:
-    """Disabled-path stage: no clock reads, `fence` is identity (keeps
-    JAX async pipelining untouched)."""
-    __slots__ = ()
-
-    def fence(self, x: Any) -> Any:
-        return x
-
-    def set(self, **tags) -> None:
-        pass
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._annotation.__exit__(*exc)
         return False
 
 
-_NULL_STAGE = _NullStage()
+def host_span(tracer: Optional[Tracer], name: str,
+              virt_t: Optional[float] = None, **tags) -> Stage:
+    """The span ``name`` of one record or window: a profiler annotation,
+    and an `obs` span event when the tracer is enabled.  Never fences."""
+    tracer = tracer if tracer is not None else get_tracer()
+    return Stage(name, tracer.span(name, virt_t=virt_t, **tags), False,
+                 tags)
 
 
 def timed_stage(tracer: Optional[Tracer], name: str,
-                virt_t: Optional[float] = None, **tags):
-    """Span context for one host-observed pipeline stage.
+                virt_t: Optional[float] = None, **tags) -> Stage:
+    """The span ``stage.<name>`` of one host pipeline stage: a profiler
+    annotation, and under ``stage_timings`` a fenced `obs` span event.
 
         with timed_stage(self.obs, "window.device", window=w) as st:
             out = self._window_fn(...)
-            st.fence(out)           # block_until_ready before the clock stops
+            st.fence(out)           # blocks only under stage_timings
     """
     tracer = tracer if tracer is not None else get_tracer()
-    if not (tracer.enabled and tracer.stage_timings):
-        return _NULL_STAGE
-    return _TimedStage(tracer, name, virt_t, tags)
+    timed = tracer.enabled and tracer.stage_timings
+    span = (tracer.span(f"stage.{name}", virt_t=virt_t, **tags) if timed
+            else _NULL_SPAN)
+    return Stage(f"stage.{name}", span, timed, tags)
+
+
+class GcSpans:
+    """While open, each Python garbage collection is a ``py.gc`` span on
+    the profiler's clock, with its generation as an argument."""
+
+    def __init__(self):
+        self._open = None       # the annotation of a running collection
+
+    def _callback(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._open = _annotation("py.gc", generation=info["generation"])
+            self._open.__enter__()
+        elif self._open is not None:
+            ann, self._open = self._open, None
+            ann.__exit__(None, None, None)
+
+    def __enter__(self):
+        gc.callbacks.append(self._callback)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self._callback)
+        return False
 
 
 # ---------------------------------------------------------------------------

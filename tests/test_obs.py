@@ -33,7 +33,6 @@ from repro.net import LinkProfile, NetSim
 from repro.obs import (MemorySink, MetricsRegistry, TraceEvent, Tracer,
                        bench_kernel, chrome_trace, read_events, read_jsonl,
                        timed_stage, use_tracer)
-from repro.obs.timers import _NULL_STAGE
 
 
 # ---------------------------------------------------------------------------
@@ -278,16 +277,23 @@ def test_read_jsonl_strict_false_drops_exactly_one(tmp_path):
 # unit: timers
 # ---------------------------------------------------------------------------
 
-def test_timed_stage_gating():
-    off = Tracer(enabled=False)
-    assert timed_stage(off, "x") is _NULL_STAGE
-    on_untimed = Tracer([MemorySink()], enabled=True, stage_timings=False)
-    assert timed_stage(on_untimed, "x") is _NULL_STAGE, \
-        "stage timing must be a separate opt-in (fencing changes perf)"
+def test_timed_stage_gating(monkeypatch):
+    fenced = []
+    monkeypatch.setattr(obs.timers, "fence",
+                        lambda x: fenced.append(x) or x)
+    # disabled, or enabled without stage timings: no event, no fence
+    # (stage timing is a separate opt-in: fencing changes perf)
+    quiet = MemorySink()
+    for tracer in (Tracer(enabled=False),
+                   Tracer([quiet], enabled=True, stage_timings=False)):
+        with timed_stage(tracer, "x") as st:
+            assert st.fence({"a": 1}) == {"a": 1}
+    assert fenced == [] and quiet.events == []
     sink = MemorySink()
     on = Tracer([sink], enabled=True, stage_timings=True)
     with timed_stage(on, "round.device", round=3) as st:
         assert st.fence({"a": 1}) == {"a": 1}
+    assert fenced == [{"a": 1}]
     (ev,) = sink.events
     assert ev.name == "stage.round.device" and ev.tags == {"round": 3}
 

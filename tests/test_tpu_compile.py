@@ -4,13 +4,16 @@ TPU v5e chip, at the paper CNN's width.
 Nothing runs: each test lowers a kernel with ``interpret=False`` against a
 v5e topology that the installed TPU compiler can describe without a chip
 attached, compiles it, and checks that the program holds a Mosaic
-``tpu_custom_call``.  This catches what interpret mode hides: block shapes
+``tpu_custom_call`` named after its kernel (the `pallas_call`'s ``name``,
+which a profile's op names carry).  This catches what interpret mode hides: block shapes
 that do not tile, casts the chip has no instruction for, VMEM overruns.
 
 The topology is described inside a module fixture, never at import, so
 that every pytest-xdist worker collects the same tests and only the worker
 given this file loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -24,6 +27,10 @@ from repro.kernels.wire_bytes import nnz_fleet
 from repro.models.cnn import cnn_flat_layout
 
 COHORT = 64
+# the `name=` each kernel's pallas_call gives its custom call
+KERNEL_NAMES = {"upload_fused": "upload_fused", "window_fold": "window_fold",
+                "nnz": "wire_bytes", "sparsify": "sparsify",
+                "ldp_perturb": "ldp_noise"}
 
 
 @pytest.fixture(scope="module")
@@ -84,4 +91,6 @@ def test_fleet_kernel_compiles_for_v5e(one_chip, name):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in arg_shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert re.search(rf"%{KERNEL_NAMES[name]}(\.\d+)? = .* custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"',
+                     compiled.as_text())
