@@ -601,6 +601,8 @@ class FleetEngine(MeshStateIO):
         mx.histogram("round.size", WINDOW_SIZE_EDGES).observe(
             rec.n_participating)
         mx.counter("round.participants").inc(rec.n_participating)
+        mx.counter("local_sgd.onehot_selects").inc(
+            rec.n_participating * self.cfg.local_steps)
         mx.counter("round.rejected").inc(rec.n_rejected)
         mx.counter("round.comm_bytes").inc(rec.comm_bytes)
         mx.gauge("model.accuracy").set(rec.accuracy)

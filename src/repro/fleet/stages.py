@@ -41,6 +41,7 @@ from ..core import aldp, detection
 # scope in its `op_name` metadata and a profile's device ops can be put to
 # the stage that issued them.  Scopes change metadata only, never the ops.
 LOCAL_SGD = "fleet.local_sgd"       # cohort gather, local SGD, deltas
+BATCH_SELECT = "batch_select"       # a local step's minibatch, in LOCAL_SGD
 UPLOAD = "fleet.upload"             # attack, DGC + ALDP, residual scatter
 CLOUD_SCORE = "fleet.cloud_score"   # rebuild + cloud accuracy, Alg. 2
 FOLD = "fleet.fold"                 # aggregation / window fold, mix, trust
@@ -62,17 +63,45 @@ def scoped(name: str, fn):
 # stage: node-local minibatch SGD
 # ---------------------------------------------------------------------------
 
+def select_rows(x, idx):
+    """``x[idx]`` for a shard ``x`` of M rows and a vector ``idx`` in
+    [0, M), taken as ``one_hot(idx, M) @ x`` on the MXU: a vmapped gather
+    of rows runs element by element on the chip.  The product is at
+    ``Precision.HIGHEST``, which on the TPU splits an f32 operand into
+    three bf16 parts: each part times 1 and every ``0 · x`` is exact, so
+    for finite ``x`` the rows are the gather's bit for bit.  At the
+    default precision the TPU would round ``x`` to bf16.
+
+    The rows are flattened to M x D for the product.  Contracted in place
+    instead, the v5e compiler writes them as bf16 straight into the
+    default-precision convolutions that read them, which read an f32
+    operand more finely than that: trained params then lie farther from
+    a ``HIGHEST`` step.  Flattened, they stay f32 and reach the
+    convolutions as the gather's did, through one layout copy."""
+    m = x.shape[0]
+    sel = (idx[:, None] == jnp.arange(m)).astype(x.dtype)
+    rows = jnp.einsum("bm,md->bd", sel, x.reshape(m, -1),
+                      precision=jax.lax.Precision.HIGHEST)
+    return rows.reshape(idx.shape + x.shape[1:])
+
+
 def make_local_train(loss_fn, local_steps: int, lr: float, batch_size: int):
     """Single-node local SGD body; identical math/key-use to the sequential
     trainer's `_local_train_impl` (bounds from `size`, not the padded shard
     length). The sync engine vmaps it with the global params broadcast
     (`in_axes=(None, ...)`); the async engine with per-node dispatched
-    params (`in_axes=(0, ...)`)."""
+    params (`in_axes=(0, ...)`).
+
+    Each step's minibatch comes from `select_rows`, under the scope
+    `BATCH_SELECT`: a one-hot product at ``Precision.HIGHEST``, the only
+    precision at which the TPU keeps f32 rows whole, so the minibatch is
+    bit-identical to the gather ``x[idx]``.  The labels are gathered."""
 
     def local_train(params, x, y, size, key):
         def body(p, k):
             idx = jax.random.randint(k, (batch_size,), 0, size)
-            batch = {"x": x[idx], "y": y[idx]}
+            with jax.named_scope(BATCH_SELECT):
+                batch = {"x": select_rows(x, idx), "y": y[idx]}
             g = jax.grad(lambda pp: loss_fn(pp, batch)[0])(p)
             return jax.tree.map(lambda a, b: a - lr * b, p, g), None
 

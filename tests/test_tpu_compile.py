@@ -12,6 +12,7 @@ The topology is described inside a module fixture, never at import, so
 that every pytest-xdist worker collects the same tests and only the worker
 given this file loads the TPU library.
 """
+import collections
 import re
 
 import jax
@@ -94,3 +95,84 @@ def test_fleet_kernel_compiles_for_v5e(one_chip, name):
     assert re.search(rf"%{KERNEL_NAMES[name]}(\.\d+)? = .* custom-call\(.*"
                      r'custom_call_target="tpu_custom_call"',
                      compiled.as_text())
+
+
+def test_onehot_selection_compiles_at_highest_for_v5e(one_chip):
+    """Local SGD's one-hot minibatch product, vmapped over 1,000 nodes of
+    60 28x28 images, keeps ``Precision.HIGHEST`` in the chip's program:
+    at a lower precision the TPU would round the selected rows to bf16."""
+    from repro.fleet.stages import select_rows
+    x = jax.ShapeDtypeStruct((1000, 60, 28, 28, 1), jnp.float32,
+                             sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((1000, 128), jnp.int32, sharding=one_chip)
+    text = jax.jit(jax.vmap(select_rows)).lower(x, idx).compile().as_text()
+    products = [l for l in text.splitlines()
+                if re.search(r"= f32\[1000,128,784\]\S* convolution\(", l)]
+    assert products
+    assert all("operand_precision={highest,highest}" in l for l in products)
+    assert " gather(" not in text
+
+
+def _element_types(text, selection=False):
+    """Each instruction of a compiled module that outputs floats, fused
+    bodies included, outside the minibatch selection (or, with
+    ``selection``, inside it), as (op_name, opcode, float element types
+    of its output), counted; instructions with no op_name (the compiler's
+    layout copies) count as ("", opcode, types).  Integer index
+    arithmetic is left out: its op_names follow how the selection's
+    indices are built."""
+    out = collections.Counter()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([\w-]+)\(", line)
+        if m is None or m.group(2) in ("parameter", "get-tuple-element",
+                                       "tuple", "bitcast", "constant"):
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        name = name.group(1) if name else ""
+        types = tuple(re.findall(r"\b(bf16|f16|f32|f64)\[", m.group(1)))
+        if types and ("batch_select" in name) == selection:
+            out[(name, m.group(2), types)] += 1
+    return out
+
+
+def test_local_sgd_keeps_the_gathers_element_types_for_v5e(one_chip,
+                                                           monkeypatch):
+    """Compiled for the chip at the 1,000-node cell's shape (60-row shards,
+    B=128, 10 steps of the paper CNN), local SGD through the one-hot
+    product writes the rows as f32, as the gather ``x[idx]`` does, and
+    runs every op outside the selection at the element types it has
+    through the gather: no intermediate of the CNN's step is narrowed to
+    bf16, the selected rows included."""
+    from repro.fleet import stages
+    from repro.models.cnn import cnn_loss, init_cnn
+    c, m = 1000, 60
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(init_cnn, jax.random.PRNGKey(0)))
+    keys = jax.eval_shape(lambda k: jax.random.split(k, c),
+                          jax.random.PRNGKey(0))
+    args = (params,
+            jax.ShapeDtypeStruct((c, m, 28, 28, 1), jnp.float32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((c, m), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((c,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct(keys.shape, keys.dtype, sharding=one_chip))
+
+    def compiled():
+        lt = stages.make_local_train(cnn_loss, 10, 0.1, 128)
+        f = jax.jit(jax.vmap(lt, in_axes=(None, 0, 0, 0, 0)))
+        return f.lower(*args).compile().as_text()
+
+    product = compiled()
+    monkeypatch.setattr(stages, "select_rows", lambda x, idx: x[idx])
+    gather = compiled()
+    assert re.search(r"= f32\[1000,128,784\]\S* convolution\(.*"
+                     r"operand_precision=\{highest,highest\}", product)
+    assert all(k[2] == ("f32",) for k in _element_types(product, True))
+    a, b = _element_types(product), _element_types(gather)
+    assert {k: n for k, n in a.items() if k[0]} == \
+        {k: n for k, n in b.items() if k[0]}
+    # of the unnamed layout copies, only f32 ones may differ (the gather
+    # path copies the shards into its own layout)
+    assert sum(n for k, n in a.items() if "bf16" in k[2]) == \
+        sum(n for k, n in b.items() if "bf16" in k[2])
