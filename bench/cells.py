@@ -4,15 +4,17 @@ A workload entry names a configuration and a traffic mix.  The
 configuration's `file` holds its sizes; the traffic mix is
 `bench/traffic/<traffic>.json`; the limits of the correctness check are
 `bench/limits/<cell>.json`; a per-layer metric is read by
-`bench/metrics/<metric>.py`.  Adding a cell, a configuration or a metric
-adds files and entries and edits none."""
+`bench/metrics/<metric>.py`; the model a configuration names under its
+`"model"` key is `bench/models/<model>.py`.  Adding a cell, a
+configuration, a model or a metric adds files and entries and edits
+none."""
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import json
 import os
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -54,11 +56,51 @@ def cell(name: str, bench: Optional[dict] = None, root: str = ROOT) -> Cell:
                 [m for m in bench["per_layer"] if _reports(m, name)])
 
 
+def _load(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str):
     """The `read(run)` function of `bench/metrics/<name>.py`."""
     path = os.path.join(BENCH, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, f"bench_metric_{name.replace('.', '_')}").read
+
+
+_MODELS: Dict[str, object] = {}
+
+
+def model_module(config: dict):
+    """`bench/models/<config["model"]>.py`, loaded once per path: the
+    reference's jitted functions take the module as a static argument, so
+    one module object keeps one compiled program.
+
+    What a model module gives the benchmark, each from the configuration
+    (`SETTINGS` names the configuration's keys it reads):
+      inputs     `make_inputs(config, seed)`: a dict of the initial
+                 trainable tree `params`, the node shards `x` and targets
+                 `y` (any shape and dtype, nodes first), the `test` and
+                 `cloud` sets, the `malicious` ids, and optionally `extra`
+                 arrays the generic code never reads; nothing of the
+                 program is imported for them;
+      program    `spec_fields(config)`, the `FleetSpec` fields that belong
+                 to the model, and `program_fns()`, the `loss_fn` and
+                 `acc_fn` of `api.Population`: the only part that imports
+                 the program;
+      reference  `forward(p, x, precision)` and `loss(p, x, y, precision)`
+                 in plain `jax.numpy`, `accuracy(logits, y)` as the
+                 program reports it, `NODE_BLOCK` and `TEST_BLOCK`, and
+                 `ALTERED`, the key path of the leaf the `altered` fault
+                 doubles;
+      counts     `n_params`, `update_flops` and `record_flops`;
+      control    `control_kwargs(config)`: the `reference.run` keywords
+                 that compute below the configuration's stated precision."""
+    path = os.path.join(BENCH, "models", f"{config['model']}.py")
+    if path not in _MODELS:
+        if not os.path.isfile(path):
+            raise FileNotFoundError(
+                f"model {config['model']!r}: no module {path}")
+        _MODELS[path] = _load(path, f"bench_model_{config['model']}")
+    return _MODELS[path]

@@ -30,7 +30,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -50,9 +50,23 @@ class Readings:
     comm_bytes: List[float]
 
 
+def leaf_paths(tree) -> List[Tuple[str, ...]]:
+    """The key paths of a nested dict's leaves in sorted-key order: the
+    order of the leaves in a node's flat upload."""
+    if not isinstance(tree, dict):
+        return [()]
+    return [(k,) + p for k in sorted(tree) for p in leaf_paths(tree[k])]
+
+
+def leaf(tree, path: Tuple[str, ...]):
+    for k in path:
+        tree = tree[k]
+    return tree
+
+
 def _leaves(tree) -> Dict[str, np.ndarray]:
-    return {f"{a}.{b}": np.asarray(tree[a][b], np.float64)
-            for a in sorted(tree) for b in sorted(tree[a])}
+    return {".".join(p): np.asarray(leaf(tree, p), np.float64)
+            for p in leaf_paths(tree)}
 
 
 def _norms(after, before) -> Dict[str, float]:

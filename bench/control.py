@@ -8,7 +8,8 @@ not run this.
 
 For every seed of `--seeds`: the program's first records, driven as a
 run drives them, against the float32 reference (the lower readings).
-For `--control-seeds`: the reference computed in bfloat16, put in the
+For `--control-seeds`: the reference computed below the configuration's
+stated precision (its model module's `control_kwargs`), put in the
 program's place (the control, whose smallest reading is the upper end).
 For `--fault-seeds`: the reference with each planted fault of
 `reference.FAULTS` in the program's place.  One JSON line per reading,
@@ -30,8 +31,8 @@ def _seeds(text: str):
 def readings(cell, seed: int, program: bool, control: bool, faults: bool):
     """The runs asked for on one seed, each judged against the float32
     reference by the cell's limits: one dict per run."""
-    import jax.numpy as jnp
     from bench import check, drive, population, reference
+    from bench.cells import model_module
     config, traffic = cell.config, cell.traffic
     n, k = config["n_nodes"], traffic["check_records"]
     limits = check.limits(cell.name)
@@ -46,7 +47,8 @@ def readings(cell, seed: int, program: bool, control: bool, faults: bool):
         runs.append(("program", prog))
     if control:
         runs.append(("control", reference.run(
-            config, traffic, inputs, seed, k, dtype=jnp.bfloat16)))
+            config, traffic, inputs, seed, k,
+            **model_module(config).control_kwargs(config))))
     if faults:
         runs += [(f"fault.{f}", reference.run(
             config, traffic, inputs, seed, k, fault=f))

@@ -16,6 +16,7 @@ import dataclasses
 import time
 from typing import List, Optional
 
+from .cells import model_module
 from .check import Readings
 from .population import Inputs
 
@@ -29,19 +30,17 @@ CACHE_EVENTS = COMPILE_EVENTS + ("/jax/compilation_cache/cache_hits",
 
 def build_spec(config: dict, traffic: dict, seed: int):
     """The cell's `ExperimentSpec`: the paper's ALDPFL setting
-    (`chip_smoke.paper_spec`) with the sizes of `config` and the schedule
-    and node profile of `traffic`, on one chip."""
+    (`chip_smoke.paper_spec`) with the sizes of `config`, the model's own
+    `FleetSpec` fields (its module's `spec_fields`) and the schedule and
+    node profile of `traffic`, on one chip."""
     from repro import api
     return api.ExperimentSpec(
         fleet=api.FleetSpec(
-            n_nodes=config["n_nodes"], model=config["model"],
-            hw=tuple(config["hw"]), n_classes=config["n_classes"],
+            n_nodes=config["n_nodes"],
             samples_per_node=config["samples_per_node"],
             n_test=config["n_test"], n_cloud_test=config["n_cloud_test"],
             profile=api.NodeHeterogeneity(**traffic["profile"]),
-            attack=api.AttackMix(malicious_frac=config["malicious_frac"],
-                                 flip_src=config["flip_src"],
-                                 flip_dst=config["flip_dst"])),
+            **model_module(config).spec_fields(config)),
         schedule=api.SchedulePolicy(kind=traffic["schedule"],
                                     alpha=config["alpha"]),
         privacy=api.PrivacySpec(sigma=config["sigma"],
@@ -58,13 +57,14 @@ def build_spec(config: dict, traffic: dict, seed: int):
         rounds=ROUNDS, seed=int(seed))
 
 
-def population(inputs: Inputs):
-    """The program's `Population` over the benchmark's inputs."""
+def population(config: dict, inputs: Inputs):
+    """The program's `Population` over the benchmark's inputs, with the
+    model's `loss_fn` and `acc_fn` (its module's `program_fns`)."""
     from repro import api
     from repro.fleet import NodeProfile
-    from repro.models.cnn import cnn_accuracy, cnn_loss
+    loss_fn, acc_fn = model_module(config).program_fns()
     return api.Population(
-        params=inputs.params, loss_fn=cnn_loss, acc_fn=cnn_accuracy,
+        params=inputs.params, loss_fn=loss_fn, acc_fn=acc_fn,
         node_data=[(inputs.x[i], inputs.y[i])
                    for i in range(inputs.x.shape[0])],
         test_data=inputs.test, cloud_test=inputs.cloud,
@@ -86,7 +86,7 @@ class System:
 def build(config: dict, traffic: dict, seed: int, inputs: Inputs) -> System:
     from repro import api
     plan = api.compile_plan(build_spec(config, traffic, seed))
-    pop = population(inputs)
+    pop = population(config, inputs)
     state = api.init_state(plan, pop)
     return System(state, api.make_stepper(plan, pop, state))
 

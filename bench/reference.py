@@ -22,9 +22,18 @@ noise the fused upload kernel draws (a murmur3 finalizer of each
 element's in-block index, seeded per node and block).  Both are written
 out here from their definitions.
 
-`dtype=bfloat16` computes everything in bfloat16: the control, the
-precision below the configuration's float32.  `fault` plants one of the
-faults the check has to catch (see `FAULTS`)."""
+The model is the configuration's module (`bench/models/<model>.py`):
+its `loss` and `forward` in plain `jax.numpy`, its `accuracy` as the
+program reports it, `NODE_BLOCK` nodes trained at once, `TEST_BLOCK`
+rows of a test pass, and `ALTERED`, the leaf the `altered` fault
+doubles.  The round above is written once for every model; a node's flat
+upload takes the leaves in sorted-key order (`check.leaf_paths`).
+
+`dtype` is what the round computes in, at `HIGHEST` precision in
+float32 and `DEFAULT` below it.  The module's `control_kwargs` give the
+control's: the round computed below the configuration's stated
+precision.  `fault` plants one of the faults the check has to catch (see
+`FAULTS`)."""
 from __future__ import annotations
 
 import math
@@ -35,51 +44,37 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .check import Readings
+from .cells import model_module
+from .check import Readings, leaf, leaf_paths
 from .population import Inputs
 
-# the order of the leaves in a node's flat upload (the param tree's
-# sorted-key order)
-LEAVES = (("conv1", "b"), ("conv1", "w"), ("conv2", "b"), ("conv2", "w"),
-          ("fc", "b"), ("fc", "w"))
 LANE = 1024
-# nodes per block: the reference trains the fleet block by block
-BLOCK = 100
 FAULTS = ("half", "altered")
 
 
-def leaf(tree, name):
-    return tree[name[0]][name[1]]
-
-
 def flatten(tree) -> jnp.ndarray:
-    return jnp.concatenate([leaf(tree, n).reshape(-1) for n in LEAVES])
+    return jnp.concatenate([leaf(tree, n).reshape(-1)
+                            for n in leaf_paths(tree)])
 
 
 def unflatten(flat, like) -> dict:
     out, off = {}, 0
-    for a, b in LEAVES:
-        shape = like[a][b].shape
+    for path in leaf_paths(like):
+        shape = leaf(like, path).shape
         size = int(np.prod(shape))
-        out.setdefault(a, {})[b] = flat[off:off + size].reshape(shape)
+        node = out
+        for k in path[:-1]:
+            node = node.setdefault(k, {})
+        node[path[-1]] = flat[off:off + size].reshape(shape)
         off += size
     return out
 
 
-def forward(p, x, precision):
-    def conv(h, w, b):
-        return jax.lax.conv_general_dilated(
-            h, w, (2, 2), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            precision=precision) + b
-    h = jax.nn.relu(conv(x, p["conv1"]["w"], p["conv1"]["b"]))
-    h = jax.nn.relu(conv(h, p["conv2"]["w"], p["conv2"]["b"]))
-    h = h.reshape(h.shape[0], -1)
-    return jnp.dot(h, p["fc"]["w"], precision=precision) + p["fc"]["b"]
-
-
-def nll(p, x, y, precision):
-    logp = jax.nn.log_softmax(forward(p, x, precision))
-    return -jnp.take_along_axis(logp, y[:, None], axis=-1).mean()
+def leaf_offset(tree, path) -> int:
+    """Where a leaf starts in the flat upload."""
+    paths = leaf_paths(tree)
+    return sum(int(np.prod(leaf(tree, q).shape))
+               for q in paths[:paths.index(tuple(path))])
 
 
 def noise_seed(k2) -> jnp.ndarray:
@@ -117,8 +112,8 @@ def hash_noise(seed, p: int, sigma_s: float) -> jnp.ndarray:
         2.0 * math.pi * uniform(2))
 
 
-@partial(jax.jit, static_argnames=("cfg", "dtype", "precision"))
-def _block(p, x, y, k1s, k2s, res, cx, cfg, dtype, precision):
+@partial(jax.jit, static_argnames=("cfg", "model", "dtype", "precision"))
+def _block(p, x, y, k1s, k2s, res, cx, cfg, model, dtype, precision):
     """One block of nodes: local SGD, DGC, clip and noise; returns the
     uploads, the new residuals, the nonzero counts and the cloud's logits
     of each node's model."""
@@ -130,14 +125,14 @@ def _block(p, x, y, k1s, k2s, res, cx, cfg, dtype, precision):
     def node(x, y, k1, k2, r):
         def body(q, k):
             idx = jax.random.randint(k, (bs,), 0, size)
-            g = jax.grad(nll)(q, x[idx], y[idx], precision)
+            g = jax.grad(model.loss)(q, x[idx], y[idx], precision)
             return jax.tree.map(lambda a, b: a - jnp.asarray(lr, dtype) * b,
                                 q, g), None
 
         q, _ = jax.lax.scan(body, p, jax.random.split(k1, steps))
         comb = flatten(q) - pf + r
         thr, off = [], 0
-        for name in LEAVES:
+        for name in leaf_paths(p):
             n = int(np.prod(leaf(p, name).shape))
             seg = jnp.abs(comb[off:off + n])
             thr.append(jnp.full((n,), jnp.quantile(seg, 1.0 - ratio)))
@@ -153,7 +148,7 @@ def _block(p, x, y, k1s, k2s, res, cx, cfg, dtype, precision):
 
     ups, newr, nnz = jax.vmap(node)(x, y, k1s, k2s, res)
     logits = jax.lax.map(
-        lambda u: forward(unflatten(pf + u, p), cx, precision), ups)
+        lambda u: model.forward(unflatten(pf + u, p), cx, precision), ups)
     return ups, newr, nnz, logits
 
 
@@ -167,25 +162,20 @@ def _key_chain(key, n):
     return key, k1s, k2s
 
 
-@partial(jax.jit, static_argnames=("precision",))
-def _logits(p, x, precision):
-    return forward(p, x, precision)
+@partial(jax.jit, static_argnames=("model", "precision"))
+def _logits(p, x, model, precision):
+    return model.forward(p, x, precision)
 
 
-def _accuracy(logits, y) -> np.ndarray:
-    """Share of rows whose first largest logit is the label, in float32
-    as the program reports it."""
-    hits = (np.asarray(logits).argmax(-1) == np.asarray(y)).sum(-1)
-    return np.float32(hits) / np.float32(np.asarray(y).shape[-1])
-
-
-def _record(out: Readings, p, tx, ty, precision) -> None:
+def _record(out: Readings, p, tx, ty, model, precision) -> None:
     """A record's params (host copy) and test accuracy, in blocks of the
     test set."""
-    logits = np.concatenate([np.asarray(_logits(p, tx[i:i + 2000], precision))
-                             for i in range(0, tx.shape[0], 2000)])
+    b = model.TEST_BLOCK
+    logits = np.concatenate([np.asarray(_logits(p, tx[i:i + b], model,
+                                                precision))
+                             for i in range(0, tx.shape[0], b)])
     out.params.append(jax.tree.map(lambda a: np.asarray(a, np.float32), p))
-    out.accuracy.append(float(_accuracy(logits, ty)))
+    out.accuracy.append(float(model.accuracy(logits, ty)))
 
 
 def wire_bytes(nnz: np.ndarray, n_params: int) -> float:
@@ -195,9 +185,18 @@ def wire_bytes(nnz: np.ndarray, n_params: int) -> float:
     return float(np.sum(4 + (nnz * bits + 7) // 8 + 4 * nnz))
 
 
+def _cast(a, dtype):
+    """Floating inputs in the round's dtype; tokens and labels as they
+    are."""
+    a = np.asarray(a)
+    return jnp.asarray(a, dtype) if np.issubdtype(a.dtype, np.floating) \
+        else jnp.asarray(a)
+
+
 def run_sync(config: dict, inputs: Inputs, seed: int, rounds: int, *,
              dtype=jnp.float32, fault: Optional[str] = None) -> Readings:
     """`rounds` synchronous ALDPFL rounds from the inputs' weights."""
+    model = model_module(config)
     precision = (jax.lax.Precision.HIGHEST if dtype == jnp.float32
                  else jax.lax.Precision.DEFAULT)
     cfg = (config["local_steps"], config["batch_size"], config["lr"],
@@ -206,24 +205,24 @@ def run_sync(config: dict, inputs: Inputs, seed: int, rounds: int, *,
     p = jax.tree.map(lambda a: jnp.asarray(a, dtype), inputs.params)
     n_par = int(flatten(p).shape[0])
     res = jnp.zeros((n, n_par), dtype)
-    cx = jnp.asarray(inputs.cloud[0], dtype)
-    tx = jnp.asarray(inputs.test[0], dtype)
+    cx = _cast(inputs.cloud[0], dtype)
+    tx = _cast(inputs.test[0], dtype)
     key = jax.random.PRNGKey(int(seed))
     alpha = config["alpha"]
     out = Readings([], [], [], [])
     for _ in range(rounds):
         key, k1s, k2s = _key_chain(key, n)
         ups, nnz, accs, new_res = [], [], [], []
-        for lo in range(0, n, BLOCK):
-            hi = min(n, lo + BLOCK)
+        for lo in range(0, n, model.NODE_BLOCK):
+            hi = min(n, lo + model.NODE_BLOCK)
             u, r, z, lg = _block(
-                p, jnp.asarray(inputs.x[lo:hi], dtype),
+                p, _cast(inputs.x[lo:hi], dtype),
                 jnp.asarray(inputs.y[lo:hi]), k1s[lo:hi], k2s[lo:hi],
-                res[lo:hi], cx, cfg, dtype, precision)
+                res[lo:hi], cx, cfg, model, dtype, precision)
             ups.append(u)
             new_res.append(r)
             nnz.append(np.asarray(z))
-            accs.append(_accuracy(lg, inputs.cloud[1]))
+            accs.append(model.accuracy(lg, inputs.cloud[1]))
         res = jnp.concatenate(new_res)
         accs = np.concatenate(accs)
         thr = np.percentile(accs, config["detect_s"])
@@ -239,9 +238,10 @@ def run_sync(config: dict, inputs: Inputs, seed: int, rounds: int, *,
         mean = (w * omegas).sum(0) / jnp.maximum(w.sum(), 1)
         new = alpha * pf + (1 - alpha) * mean
         if fault == "altered":      # the fold's answer altered for one leaf
-            new = new.at[:leaf(p, LEAVES[0]).size].multiply(2)
+            lo = leaf_offset(p, model.ALTERED)
+            new = new.at[lo:lo + leaf(p, model.ALTERED).size].multiply(2)
         p = unflatten(new.astype(dtype), p)
-        _record(out, p, tx, inputs.test[1], precision)
+        _record(out, p, tx, inputs.test[1], model, precision)
         out.comm_bytes.append(wire_bytes(np.concatenate(nnz), n_par))
     return out
 

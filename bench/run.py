@@ -55,7 +55,7 @@ class RunView:
         self.chips, self.peaks = cell.chips, peaks
         self.records = window.records
         self.updates = window.records * cell.config["n_nodes"]
-        self.n_params = work.cnn_params(**work.model_dims(cell.config))
+        self.n_params = work.n_params(cell.config)
         self.window_s = tr.window_s(trace)
 
 

@@ -213,6 +213,16 @@ def scope_ms_per_record(run, scope: str) -> Optional[float]:
     return None if ns is None else ns / 1e6 / rounds
 
 
+def op_seconds(run, op: str, scope: str) -> float:
+    """Device seconds in the window of the ops named ``%<op>`` or
+    ``%<op>.N`` under `scope`, each op's duration as `trace.matches`
+    takes it."""
+    scopes = _scopes(run)
+    return sum(s for m, s in tr.matches(run.trace,
+                                        rf"^%{re.escape(op)}(?:\.\d+)? = ")
+               if op_scope(m.string, scopes) == scope)
+
+
 def breakdown(run) -> dict:
     """Where a record's device time and exposed idle went: device ms per
     record by scope (and `UNSCOPED`), the share of busy time under some

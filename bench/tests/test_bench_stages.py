@@ -8,7 +8,7 @@ import types
 
 import pytest
 
-from bench import cells, stages, trace
+from bench import cells, peaks, stages, trace, work
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 READERS = ("local_sgd.ms_per_record", "cloud_score.ms_per_record",
@@ -17,6 +17,37 @@ READERS = ("local_sgd.ms_per_record", "cloud_score.ms_per_record",
 
 def read(name, run):
     return cells.metric_reader(name)(run)
+
+
+# the readers by shape that the readers by scope and name replaced: any
+# 2-D f32 sort, and any custom call with the upload kernel's outputs
+DGC_SORT = r"^%sort[.\d]* = \(f32\[\d+,\d+\]"
+UPLOAD_CALL = (r"= \(f32\[(\d+),(\d+),1024\](\{[^}]*\}), f32\[\1,\2,1024\]"
+               r"\S*, s32\[\1,1,128\]\S*\) custom-call\(")
+
+
+def dgc_by_shape(run):
+    seconds = sum(s for _, s in trace.matches(run.trace, DGC_SORT))
+    return 1e3 * seconds / run.records if seconds > 0 else None
+
+
+def upload_by_shape(run):
+    calls = [(m, s) for m, s in trace.matches(run.trace, UPLOAD_CALL)
+             if "S(1)" not in m.group(3)]
+    seconds = sum(s for _, s in calls)
+    if not calls or seconds <= 0:
+        return None
+    nbytes = sum(work.upload_fused_bytes(int(m.group(1)), run.n_params)
+                 for m, _ in calls)
+    return 100.0 * nbytes / run.peaks["hbm_bytes_per_s"] / seconds
+
+
+def readings(run) -> dict:
+    """Each repointed reader's value beside its shape reader's."""
+    return {name: {"by_scope_and_name": read(name, run),
+                   "by_shape": old(run)}
+            for name, old in (("dgc_threshold.ms_per_record", dgc_by_shape),
+                              ("upload_fused_roofline", upload_by_shape))}
 
 
 # ---------------------------------------------------------------------------
@@ -223,3 +254,51 @@ def test_recorded_record_reduces_to_stored_numbers(recorded):
         "fleet.local_sgd", "fleet.upload", "fleet.cloud_score",
         "fleet.fold", "fleet.evaluate", stages.UNSCOPED}
     assert b["scoped_share_of_busy"] >= 0.95
+
+
+def test_repointed_readers_read_what_the_shape_readers_read(recorded):
+    """On the recorded record, the readers by scope and name find the ops
+    the readers by shape found, and read the same value to the last
+    digit: the six DGC sorts under ``fleet.upload`` (not Alg. 2's sort of
+    1,000 scores under ``fleet.cloud_score``) and the one
+    ``%upload_fused.1`` call."""
+    run, _ = recorded
+    run = types.SimpleNamespace(**vars(run), n_params=20490,
+                                peaks=peaks.peaks("TPU v5 lite"))
+    got = readings(run)
+    for pair in got.values():
+        assert pair["by_scope_and_name"] == pair["by_shape"]
+    dgc = got["dgc_threshold.ms_per_record"]["by_scope_and_name"]
+    assert dgc == pytest.approx(18.65, rel=1e-3)
+    roof = got["upload_fused_roofline"]["by_scope_and_name"]
+    assert roof == pytest.approx(100 * 16 * 1000 * 20490 / 819e9
+                                 / 1086992e-9, rel=1e-12)
+
+
+def test_repointed_readers_take_no_op_of_another_stage_or_kernel():
+    """A 2-D f32 sort under another scope, and another kernel with the
+    upload kernel's output shapes: the readers by shape count both, the
+    readers by scope and name neither."""
+    upload = ("(f32[8,24,1024]{2,1,0:T(8,128)}, f32[8,24,1024]{2,1,0:T(8,"
+              "128)}, s32[8,1,128]{2,1,0:T(1,128)}) custom-call(")
+    sort = "(f32[8,4608]{1,0}, s32[8,4608]{1,0}) sort("
+    dev = [trace.Op(f"%sort.1 = {sort}", 0, 10),
+           trace.Op(f"%sort.2 = {sort}", 10, 30),
+           trace.Op(f"%upload_fused.1 = {upload}", 30, 40),
+           trace.Op(f"%lora_fused.3 = {upload}", 40, 60)]
+    host = [trace.Op(trace.WINDOW_SPAN, 0, 100), trace.Op("round", 0, 100)]
+    scopes = {("sort.1", sort[:-6]): "fleet.upload",
+              ("sort.2", sort[:-6]): "fleet.local_sgd"}
+    run = types.SimpleNamespace(
+        trace=trace.Trace({"/device:TPU:0": dev}, {"/host:CPU/main": host}),
+        records=1, scopes=scopes, n_params=20490,
+        peaks=peaks.peaks("TPU v5 lite"))
+    got = readings(run)
+    dgc, roof = (got["dgc_threshold.ms_per_record"],
+                 got["upload_fused_roofline"])
+    assert dgc["by_scope_and_name"] == pytest.approx(10e-9 * 1e3)
+    assert dgc["by_shape"] == pytest.approx(30e-9 * 1e3)
+    assert roof["by_scope_and_name"] == pytest.approx(
+        100 * 16 * 8 * 20490 / 819e9 / 10e-9)
+    assert roof["by_shape"] == pytest.approx(
+        100 * 2 * 16 * 8 * 20490 / 819e9 / 30e-9)
