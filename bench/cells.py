@@ -82,15 +82,22 @@ def model_module(config: dict):
       inputs     `make_inputs(config, seed)`: a dict of the initial
                  trainable tree `params`, the node shards `x` and targets
                  `y` (any shape and dtype, nodes first), the `test` and
-                 `cloud` sets, the `malicious` ids, and optionally `extra`
-                 arrays the generic code never reads; nothing of the
-                 program is imported for them;
+                 `cloud` sets, the `malicious` ids, and optionally
+                 `extra`, a dict of the module's own arrays (frozen
+                 weights, say), which generic code passes on and never
+                 looks inside; nothing of the program is imported for
+                 them;
       program    `spec_fields(config)`, the `FleetSpec` fields that belong
-                 to the model, and `program_fns()`, the `loss_fn` and
-                 `acc_fn` of `api.Population`: the only part that imports
-                 the program;
-      reference  `forward(p, x, precision)` and `loss(p, x, y, precision)`
-                 in plain `jax.numpy`, `accuracy(logits, y)` as the
+                 to the model, `program_fns()`, the `loss_fn` and `acc_fn`
+                 of `api.Population`, and `population_fields(config,
+                 inputs)`, the further `api.Population` keywords the model
+                 needs, its `extra` arrays among them (none may be one
+                 that `drive.population` sets): the only part that
+                 imports the program;
+      reference  `forward(p, x, precision, extra)` and `loss(p, x, y,
+                 precision, extra)` in plain `jax.numpy`, `extra` being
+                 the inputs' `extra` on the device, in the dtype the
+                 module stored it in, `accuracy(logits, y)` as the
                  program reports it, `NODE_BLOCK` and `TEST_BLOCK`, and
                  `ALTERED`, the key path of the leaf the `altered` fault
                  doubles;

@@ -59,11 +59,15 @@ def build_spec(config: dict, traffic: dict, seed: int):
 
 def population(config: dict, inputs: Inputs):
     """The program's `Population` over the benchmark's inputs, with the
-    model's `loss_fn` and `acc_fn` (its module's `program_fns`)."""
+    model's `loss_fn` and `acc_fn` (its module's `program_fns`) and the
+    fields its module's `population_fields` gives, which carry the
+    model's own arrays (`inputs.extra`).  A field that is set here for
+    every model (`params`, say) raises, naming it."""
     from repro import api
     from repro.fleet import NodeProfile
-    loss_fn, acc_fn = model_module(config).program_fns()
-    return api.Population(
+    model = model_module(config)
+    loss_fn, acc_fn = model.program_fns()
+    fields = dict(
         params=inputs.params, loss_fn=loss_fn, acc_fn=acc_fn,
         node_data=[(inputs.x[i], inputs.y[i])
                    for i in range(inputs.x.shape[0])],
@@ -71,6 +75,13 @@ def population(config: dict, inputs: Inputs):
         profile=NodeProfile(compute_s=inputs.compute_s,
                             bandwidth_bps=inputs.bandwidth_bps),
         malicious_ids=tuple(inputs.malicious))
+    own = model.population_fields(config, inputs)
+    clash = sorted(set(own) & set(fields))
+    if clash:
+        raise ValueError(f"model {config['model']!r}: population_fields "
+                         f"sets {clash}, which every model's Population "
+                         "already sets")
+    return api.Population(**fields, **own)
 
 
 @dataclasses.dataclass

@@ -75,6 +75,12 @@ def spec_fields(config: dict) -> dict:
                                     flip_dst=config["flip_dst"])}
 
 
+def population_fields(config: dict, inputs) -> dict:
+    """The image models bring no arrays of their own: no `Population`
+    fields beyond those every model sets."""
+    return {}
+
+
 def accuracy(logits, y) -> np.ndarray:
     """Share of rows whose first largest logit is the label, in float32
     as the program reports it."""

@@ -58,6 +58,7 @@ def make_inputs(config: dict, seed: int) -> dict:
 # ---------------------------------------------------------------------------
 
 spec_fields = _images.spec_fields
+population_fields = _images.population_fields
 
 
 def program_fns():
@@ -70,7 +71,7 @@ def program_fns():
 # reference
 # ---------------------------------------------------------------------------
 
-def forward(p, x, precision):
+def forward(p, x, precision, extra):
     def conv(h, w, b):
         return jax.lax.conv_general_dilated(
             h, w, (2, 2), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
@@ -81,8 +82,8 @@ def forward(p, x, precision):
     return jnp.dot(h, p["fc"]["w"], precision=precision) + p["fc"]["b"]
 
 
-def loss(p, x, y, precision):
-    logp = jax.nn.log_softmax(forward(p, x, precision))
+def loss(p, x, y, precision, extra):
+    logp = jax.nn.log_softmax(forward(p, x, precision, extra))
     return -jnp.take_along_axis(logp, y[:, None], axis=-1).mean()
 
 

@@ -49,6 +49,7 @@ def make_inputs(config: dict, seed: int) -> dict:
 # ---------------------------------------------------------------------------
 
 spec_fields = _images.spec_fields
+population_fields = _images.population_fields
 
 
 def program_fns():
@@ -60,14 +61,14 @@ def program_fns():
 # reference
 # ---------------------------------------------------------------------------
 
-def forward(p, x, precision):
+def forward(p, x, precision, extra):
     h = jnp.tanh(jnp.dot(x.reshape(x.shape[0], -1), p["fc1"]["w"],
                          precision=precision) + p["fc1"]["b"])
     return jnp.dot(h, p["fc2"]["w"], precision=precision) + p["fc2"]["b"]
 
 
-def loss(p, x, y, precision):
-    logp = jax.nn.log_softmax(forward(p, x, precision))
+def loss(p, x, y, precision, extra):
+    logp = jax.nn.log_softmax(forward(p, x, precision, extra))
     return -jnp.take_along_axis(logp, y[:, None], axis=-1).mean()
 
 

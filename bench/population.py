@@ -7,7 +7,11 @@ anything the program made.  The configuration's model module
 (`bench/models/<model>.py`, `make_inputs`) draws the initial trainable
 tree, the node shards, the test and cloud sets and the malicious ids from
 the seed; it may add arrays of its own (frozen weights, say) under
-`extra`, which generic code never reads.
+`extra`.  Both sides of the check get them, and generic code never looks
+inside them: the reference passes them, placed on the device once per
+run, as the last argument of the module's `loss` and `forward`, and the
+program gets them through the module's `population_fields`.  The
+trainable tree `params` alone is trained, uploaded and compared.
 
 The nodes' compute times are the same for every seed (the fleet's
 hardware).  Under the synchronous schedule they and the uplink rates set

@@ -26,8 +26,13 @@ The model is the configuration's module (`bench/models/<model>.py`):
 its `loss` and `forward` in plain `jax.numpy`, its `accuracy` as the
 program reports it, `NODE_BLOCK` nodes trained at once, `TEST_BLOCK`
 rows of a test pass, and `ALTERED`, the leaf the `altered` fault
-doubles.  The round above is written once for every model; a node's flat
-upload takes the leaves in sorted-key order (`check.leaf_paths`).
+doubles.  The module's own arrays (`Inputs.extra`, frozen weights, say)
+are placed on the device once per run, in the dtype the module stored
+them in, and reach `loss` and `forward` as their last argument, traced
+and never trained: the gradient, the flat upload, DGC, the residuals and
+the noise cover the trainable tree (`Inputs.params`) alone.  The round
+above is written once for every model; a node's flat upload takes the
+leaves in sorted-key order (`check.leaf_paths`).
 
 `dtype` is what the round computes in, at `HIGHEST` precision in
 float32 and `DEFAULT` below it.  The module's `control_kwargs` give the
@@ -113,7 +118,8 @@ def hash_noise(seed, p: int, sigma_s: float) -> jnp.ndarray:
 
 
 @partial(jax.jit, static_argnames=("cfg", "model", "dtype", "precision"))
-def _block(p, x, y, k1s, k2s, res, cx, cfg, model, dtype, precision):
+def _block(p, x, y, k1s, k2s, res, cx, extra, cfg, model, dtype,
+           precision):
     """One block of nodes: local SGD, DGC, clip and noise; returns the
     uploads, the new residuals, the nonzero counts and the cloud's logits
     of each node's model."""
@@ -125,7 +131,7 @@ def _block(p, x, y, k1s, k2s, res, cx, cfg, model, dtype, precision):
     def node(x, y, k1, k2, r):
         def body(q, k):
             idx = jax.random.randint(k, (bs,), 0, size)
-            g = jax.grad(model.loss)(q, x[idx], y[idx], precision)
+            g = jax.grad(model.loss)(q, x[idx], y[idx], precision, extra)
             return jax.tree.map(lambda a, b: a - jnp.asarray(lr, dtype) * b,
                                 q, g), None
 
@@ -148,7 +154,8 @@ def _block(p, x, y, k1s, k2s, res, cx, cfg, model, dtype, precision):
 
     ups, newr, nnz = jax.vmap(node)(x, y, k1s, k2s, res)
     logits = jax.lax.map(
-        lambda u: model.forward(unflatten(pf + u, p), cx, precision), ups)
+        lambda u: model.forward(unflatten(pf + u, p), cx, precision, extra),
+        ups)
     return ups, newr, nnz, logits
 
 
@@ -163,16 +170,16 @@ def _key_chain(key, n):
 
 
 @partial(jax.jit, static_argnames=("model", "precision"))
-def _logits(p, x, model, precision):
-    return model.forward(p, x, precision)
+def _logits(p, x, extra, model, precision):
+    return model.forward(p, x, precision, extra)
 
 
-def _record(out: Readings, p, tx, ty, model, precision) -> None:
+def _record(out: Readings, p, tx, ty, extra, model, precision) -> None:
     """A record's params (host copy) and test accuracy, in blocks of the
     test set."""
     b = model.TEST_BLOCK
-    logits = np.concatenate([np.asarray(_logits(p, tx[i:i + b], model,
-                                                precision))
+    logits = np.concatenate([np.asarray(_logits(p, tx[i:i + b], extra,
+                                                model, precision))
                              for i in range(0, tx.shape[0], b)])
     out.params.append(jax.tree.map(lambda a: np.asarray(a, np.float32), p))
     out.accuracy.append(float(model.accuracy(logits, ty)))
@@ -207,6 +214,7 @@ def run_sync(config: dict, inputs: Inputs, seed: int, rounds: int, *,
     res = jnp.zeros((n, n_par), dtype)
     cx = _cast(inputs.cloud[0], dtype)
     tx = _cast(inputs.test[0], dtype)
+    extra = jax.device_put(inputs.extra)
     key = jax.random.PRNGKey(int(seed))
     alpha = config["alpha"]
     out = Readings([], [], [], [])
@@ -218,7 +226,7 @@ def run_sync(config: dict, inputs: Inputs, seed: int, rounds: int, *,
             u, r, z, lg = _block(
                 p, _cast(inputs.x[lo:hi], dtype),
                 jnp.asarray(inputs.y[lo:hi]), k1s[lo:hi], k2s[lo:hi],
-                res[lo:hi], cx, cfg, model, dtype, precision)
+                res[lo:hi], cx, extra, cfg, model, dtype, precision)
             ups.append(u)
             new_res.append(r)
             nnz.append(np.asarray(z))
@@ -241,7 +249,7 @@ def run_sync(config: dict, inputs: Inputs, seed: int, rounds: int, *,
             lo = leaf_offset(p, model.ALTERED)
             new = new.at[lo:lo + leaf(p, model.ALTERED).size].multiply(2)
         p = unflatten(new.astype(dtype), p)
-        _record(out, p, tx, inputs.test[1], model, precision)
+        _record(out, p, tx, inputs.test[1], extra, model, precision)
         out.comm_bytes.append(wire_bytes(np.concatenate(nnz), n_par))
     return out
 

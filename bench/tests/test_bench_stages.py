@@ -302,3 +302,52 @@ def test_repointed_readers_take_no_op_of_another_stage_or_kernel():
         100 * 16 * 8 * 20490 / 819e9 / 10e-9)
     assert roof["by_shape"] == pytest.approx(
         100 * 2 * 16 * 8 * 20490 / 819e9 / 30e-9)
+
+
+# ---------------------------------------------------------------------------
+# a cell without a stage: the readers of the fleet's stages apply to every
+# cell, and one whose trace lacks a reader's scope, op or spans reads None
+# ---------------------------------------------------------------------------
+
+def _without_scope(scope):
+    def strip(run):
+        run.scopes = {k: v for k, v in run.scopes.items() if v != scope}
+    return strip
+
+
+def _without_ops(prefix):
+    def strip(run):
+        run.trace.devices = {d: [o for o in ops
+                                 if not o.name.startswith(prefix)]
+                             for d, ops in run.trace.devices.items()}
+    return strip
+
+
+def _without_program_spans(run):
+    run.trace.host = {t: [o for o in ops if not stages.is_program_span(o.name)]
+                      for t, ops in run.trace.host.items()}
+
+
+@pytest.mark.parametrize("name, strip", [
+    ("local_sgd.ms_per_record", _without_scope("fleet.local_sgd")),
+    ("cloud_score.ms_per_record", _without_scope("fleet.cloud_score")),
+    ("dgc_threshold.ms_per_record", _without_ops("%sort")),
+    ("upload_fused_roofline", _without_ops("%upload_fused")),
+    ("host.exposed_ms_per_record", _without_program_spans),
+])
+def test_stage_reader_reads_none_where_its_stage_is_missing(recorded, name,
+                                                            strip):
+    """On the recorded record each reader reads a number; with its scope,
+    op or program spans taken out, as in a cell that runs no such stage,
+    it reads None and does not raise.  The five carry no `workloads`
+    list in BENCHMARK.json: every cell reads them."""
+    base, _ = recorded
+    run = types.SimpleNamespace(
+        trace=trace.Trace(dict(base.trace.devices), dict(base.trace.host)),
+        records=base.records, scopes=dict(base.scopes), n_params=20490,
+        peaks=peaks.peaks("TPU v5 lite"))
+    assert read(name, run) > 0
+    strip(run)
+    assert read(name, run) is None
+    entry = {m["name"]: m for m in cells.load_benchmark()["per_layer"]}[name]
+    assert "workloads" not in entry
