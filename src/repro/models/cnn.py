@@ -40,6 +40,37 @@ def cnn_flat_layout(in_hw: Tuple[int, int] = (28, 28)
     return sum(sizes), tuple(sum(sizes[:i]) for i in range(len(sizes)))
 
 
+@jax.custom_vjp
+def relu(x: jnp.ndarray) -> jnp.ndarray:
+    """``jax.nn.relu`` whose backward pass keeps the bfloat16 cast of its
+    output, not the pre-activation, as the derivative's mask.
+
+    The forward value is untouched: ``max(x, 0)`` in ``x``'s dtype.  The
+    mask ``bf16(max(x, 0)) > 0`` holds exactly where ``x > 0`` does:
+    bfloat16 has float32's exponent range, so a positive normal float32
+    never rounds to zero, and a subnormal one is flushed to zero in both
+    the compare and the cast on the TPU and under XLA's CPU runtime.  On
+    the TPU that cast is the tensor the next layer's default-precision
+    convolution or matmul already reads, so the backward pass stores
+    nothing beside it; ``jax.nn.relu``'s mask keeps a float32 copy of the
+    pre-activation instead, written by the forward pass and read back only
+    to pack the mask.  Gradients are ``jax.nn.relu``'s bit for bit (0 at
+    ``x == 0``)."""
+    return jnp.maximum(x, 0)
+
+
+def _relu_fwd(x):
+    y = jnp.maximum(x, 0)
+    return y, y.astype(jnp.bfloat16)
+
+
+def _relu_bwd(mask_source, g):
+    return (jnp.where(mask_source > 0, g, jnp.zeros_like(g)),)
+
+
+relu.defvjp(_relu_fwd, _relu_bwd)
+
+
 def cnn_forward(params: dict, x: jnp.ndarray) -> jnp.ndarray:
     """x (B, H, W, C) -> logits (B, n_classes)."""
     def conv(p, h, stride):
@@ -48,8 +79,8 @@ def cnn_forward(params: dict, x: jnp.ndarray) -> jnp.ndarray:
             padding="SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
         return out + p["b"].astype(h.dtype)
 
-    h = jax.nn.relu(conv(params["conv1"], x, 2))
-    h = jax.nn.relu(conv(params["conv2"], h, 2))
+    h = relu(conv(params["conv1"], x, 2))
+    h = relu(conv(params["conv2"], h, 2))
     h = h.reshape(h.shape[0], -1)
     return h @ params["fc"]["w"].astype(h.dtype) + params["fc"]["b"].astype(h.dtype)
 

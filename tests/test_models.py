@@ -137,3 +137,97 @@ def test_edge_accuracy_bit_equal_to_argmax_form(model, cohort):
     ))(cohort_params)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert len(set(np.asarray(got).tolist())) > 1
+
+
+# ---------------------------------------------------------------------------
+# edge models: the CNN's ReLU keeps a bf16 mask source for its backward pass
+# ---------------------------------------------------------------------------
+
+def _bits(tree):
+    return [np.asarray(a).view(np.uint8) for a in jax.tree.leaves(tree)]
+
+
+def _assert_bit_equal(a, b):
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    for u, v in zip(_bits(a), _bits(b)):
+        np.testing.assert_array_equal(u, v)
+
+
+def _cnn_case(dtype, n=24, hw=(12, 12), seed=7):
+    """CNN params with some bias entries exactly 0 and a batch whose first
+    images are all zeros, so that both convolutions see exact-zero,
+    negative and positive pre-activations."""
+    from repro.models.cnn import init_cnn
+    kp, kb, kx, ky = jax.random.split(jax.random.PRNGKey(seed), 4)
+    params = init_cnn(kp, in_hw=hw)
+    for name, k in zip(("conv1", "conv2", "fc"), jax.random.split(kb, 3)):
+        b = params[name]["b"]
+        b = 0.1 * jax.random.normal(k, b.shape)
+        params[name]["b"] = b.at[::2].set(0.0)
+    x = jax.random.normal(kx, (n,) + hw + (1,)).at[:3].set(0.0)
+    y = jax.random.randint(ky, (n,), 0, 10)
+    return params, x.astype(dtype), y
+
+
+def test_relu_forward_and_gradient_bit_equal_to_jax_nn_relu():
+    from repro.models.cnn import relu
+    x = jnp.array([1e-40, -1e-40, 0.0, -0.0, 1.0, -2.0, 3e38, jnp.inf,
+                   -jnp.inf, jnp.nan, 1.2e-38, 1.1754944e-38], jnp.float32)
+    x = jnp.concatenate([x, jax.random.normal(jax.random.PRNGKey(0), (500,))])
+    w = jnp.arange(1.0, x.size + 1)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        xd = x.astype(dtype)
+        got, want = jax.jit(relu)(xd), jax.jit(jax.nn.relu)(xd)
+        assert got.dtype == want.dtype == dtype
+        _assert_bit_equal(got, want)
+        grad = lambda f: jax.jit(jax.grad(
+            lambda v: (f(v).astype(jnp.float32) * w).sum()))(xd)
+        _assert_bit_equal(grad(relu), grad(jax.nn.relu))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_cnn_loss_gradient_bit_equal_to_jax_nn_relu(dtype, monkeypatch):
+    """`jax.grad(cnn_loss)` through the bf16-mask ReLU equals the one
+    through `jax.nn.relu` on every leaf, bit for bit, over a batch with
+    exact-zero and negative pre-activations in both convolutions."""
+    from repro.models import cnn
+    params, x, y = _cnn_case(dtype)
+    pre1 = jax.lax.conv_general_dilated(
+        x, params["conv1"]["w"].astype(dtype), (2, 2), "SAME",
+        dimension_numbers=("NHWC", "HWIO", "NHWC")) + \
+        params["conv1"]["b"].astype(dtype)
+    assert bool((pre1 == 0).any()) and bool((pre1 < 0).any())
+    assert bool((pre1 > 0).any())
+
+    def grads():
+        return jax.jit(jax.grad(
+            lambda p: cnn.cnn_loss(p, {"x": x, "y": y})[0]))(params)
+
+    got = grads()
+    monkeypatch.setattr(cnn, "relu", jax.nn.relu)
+    want = grads()
+    _assert_bit_equal(got, want)
+    assert all(bool(jnp.any(g != 0)) for g in jax.tree.leaves(got))
+
+
+def test_cnn_local_train_params_bit_equal_to_jax_nn_relu(monkeypatch):
+    """Local SGD of the fleet (`make_local_train`, vmapped over 3 nodes,
+    2 steps) trains the same params, bit for bit, as with `jax.nn.relu`."""
+    from repro.fleet import stages
+    from repro.models import cnn
+    params, x, y = _cnn_case(jnp.float32, n=3 * 20)
+    xs, ys = x.reshape((3, 20) + x.shape[1:]), y.reshape(3, 20)
+    sizes = jnp.array([20, 17, 9], jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+
+    def trained():
+        lt = stages.make_local_train(cnn.cnn_loss, 2, 0.1, 8)
+        return jax.jit(jax.vmap(lt, in_axes=(None, 0, 0, 0, 0)))(
+            params, xs, ys, sizes, keys)
+
+    got = trained()
+    monkeypatch.setattr(cnn, "relu", jax.nn.relu)
+    want = trained()
+    _assert_bit_equal(got, want)
+    assert not all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in
+                   zip(jax.tree.leaves(got), jax.tree.leaves(params)))

@@ -113,6 +113,28 @@ def test_onehot_selection_compiles_at_highest_for_v5e(one_chip):
     assert " gather(" not in text
 
 
+def _cell_local_sgd(one_chip):
+    """The 1,000-node cell's local SGD (60-row shards, B=128, 10 steps of
+    the paper CNN) compiled for the described chip, as HLO text."""
+    from repro.fleet import stages
+    from repro.models.cnn import cnn_loss, init_cnn
+    c, m = 1000, 60
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(init_cnn, jax.random.PRNGKey(0)))
+    keys = jax.eval_shape(lambda k: jax.random.split(k, c),
+                          jax.random.PRNGKey(0))
+    args = (params,
+            jax.ShapeDtypeStruct((c, m, 28, 28, 1), jnp.float32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((c, m), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((c,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct(keys.shape, keys.dtype, sharding=one_chip))
+    lt = stages.make_local_train(cnn_loss, 10, 0.1, 128)
+    f = jax.jit(jax.vmap(lt, in_axes=(None, 0, 0, 0, 0)))
+    return f.lower(*args).compile().as_text()
+
+
 def _element_types(text, selection=False):
     """Each instruction of a compiled module that outputs floats, fused
     bodies included, outside the minibatch selection (or, with
@@ -144,28 +166,9 @@ def test_local_sgd_keeps_the_gathers_element_types_for_v5e(one_chip,
     through the gather: no intermediate of the CNN's step is narrowed to
     bf16, the selected rows included."""
     from repro.fleet import stages
-    from repro.models.cnn import cnn_loss, init_cnn
-    c, m = 1000, 60
-    params = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        jax.eval_shape(init_cnn, jax.random.PRNGKey(0)))
-    keys = jax.eval_shape(lambda k: jax.random.split(k, c),
-                          jax.random.PRNGKey(0))
-    args = (params,
-            jax.ShapeDtypeStruct((c, m, 28, 28, 1), jnp.float32,
-                                 sharding=one_chip),
-            jax.ShapeDtypeStruct((c, m), jnp.int32, sharding=one_chip),
-            jax.ShapeDtypeStruct((c,), jnp.int32, sharding=one_chip),
-            jax.ShapeDtypeStruct(keys.shape, keys.dtype, sharding=one_chip))
-
-    def compiled():
-        lt = stages.make_local_train(cnn_loss, 10, 0.1, 128)
-        f = jax.jit(jax.vmap(lt, in_axes=(None, 0, 0, 0, 0)))
-        return f.lower(*args).compile().as_text()
-
-    product = compiled()
+    product = _cell_local_sgd(one_chip)
     monkeypatch.setattr(stages, "select_rows", lambda x, idx: x[idx])
-    gather = compiled()
+    gather = _cell_local_sgd(one_chip)
     assert re.search(r"= f32\[1000,128,784\]\S* convolution\(.*"
                      r"operand_precision=\{highest,highest\}", product)
     assert all(k[2] == ("f32",) for k in _element_types(product, True))
@@ -176,3 +179,77 @@ def test_local_sgd_keeps_the_gathers_element_types_for_v5e(one_chip,
     # path copies the shards into its own layout)
     assert sum(n for k, n in a.items() if "bf16" in k[2]) == \
         sum(n for k, n in b.items() if "bf16" in k[2])
+
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\((.*)")
+_ARRAY = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
+
+
+def _instructions(text):
+    """(name, output shape text, opcode, rest of the line) of every
+    instruction of a compiled module, fused bodies included."""
+    return [m.groups() for m in map(_INSTR.match, text.splitlines()) if m]
+
+
+def _f32_beside_bf16(text):
+    """Fusions that output an array both as bf16 and as f32."""
+    out = []
+    for name, shape, op, _ in _instructions(text):
+        arrays = _ARRAY.findall(shape)
+        dims = lambda t: {d for e, d in arrays if e == t}
+        if op == "fusion" and dims("bf16") & dims("f32"):
+            out.append(name)
+    return out
+
+
+def _mask_reads(text):
+    """The element types and shapes each u8 or u16 fusion reads."""
+    shapes = {name: shape for name, shape, _, _ in _instructions(text)}
+    reads = []
+    for _, shape, op, rest in _instructions(text):
+        if op == "fusion" and re.match(r"u(8|16)\[", shape):
+            args = re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+            reads += [_ARRAY.findall(shapes[a]) for a in args]
+    return [a for r in reads for a in r]
+
+
+def _convolutions(text):
+    """Each convolution as (output shape with layout, operand shapes with
+    layouts, window, dim_labels, feature_group_count, operand_precision),
+    counted."""
+    shapes = {name: shape for name, shape, _, _ in _instructions(text)}
+    out = collections.Counter()
+    for _, shape, op, rest in _instructions(text):
+        if op != "convolution":
+            continue
+        operands = tuple(shapes[a] for a in
+                         re.findall(r"%([\w.\-]+)", rest.split(")")[0]))
+        attrs = tuple(
+            (m.group(1) if m else None) for m in
+            (re.search(rf"\b{k}=(\{{[^}}]*\}}|[^ ,]+)", rest)
+             for k in ("window", "dim_labels", "feature_group_count",
+                       "operand_precision")))
+        out[(shape, operands) + attrs] += 1
+    return out
+
+
+def test_local_sgd_relu_keeps_no_f32_pre_activation_for_v5e(one_chip,
+                                                           monkeypatch):
+    """At the 1,000-node cell's shape, the CNN's ReLU takes its backward
+    mask from the bf16 activation the next layer reads: no fusion writes
+    an f32 copy of a convolution's activation beside the bf16 one, and no
+    mask fusion reads conv2's f32 pre-activation.  Every convolution is
+    the one `jax.nn.relu`'s program has, which does both."""
+    from repro.models import cnn
+    conv2 = ("f32", "1000,128,7,7,32")
+    new = _cell_local_sgd(one_chip)
+    monkeypatch.setattr(cnn, "relu", jax.nn.relu)
+    old = _cell_local_sgd(one_chip)
+    assert _f32_beside_bf16(new) == []
+    assert conv2 not in _mask_reads(new)
+    assert ("bf16", "1000,128,7,7,32") in _mask_reads(new)
+    assert len(_f32_beside_bf16(old)) == 2
+    assert conv2 in _mask_reads(old)
+    convs = _convolutions(new)
+    assert sum(convs.values()) >= 6
+    assert convs == _convolutions(old)
