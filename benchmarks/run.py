@@ -1,6 +1,6 @@
 """Benchmark harness — one module per paper table/figure.
 
-  PYTHONPATH=src python -m benchmarks.run [--only fig7,kernels]
+  PYTHONPATH=src python -m benchmarks.run [--only fig7,fleet]
 
 Prints ``name,us_per_call,derived`` CSV rows.
 """
@@ -22,7 +22,6 @@ MODULES = {
     "net": "net_sweep",
     "fleet": "fleet_scale",
     "async": "async_scale",
-    "kernels": "kernels_micro",
     "roofline": "roofline_table",
     "obs": "obs_smoke",
 }

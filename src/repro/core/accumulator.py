@@ -6,9 +6,9 @@ accumulated in the gradient accumulation container" — the DGC-style scheme
 upload time the combined (residual + new gradient) tensor is split into a
 sparse large-magnitude part (uploaded) and a small-magnitude part (kept).
 
-The Pallas kernel `repro.kernels.sparsify` implements the fused
-threshold+accumulate pass for TPU; this module is the jnp reference and the
-pytree-level orchestration.
+The Pallas kernel `repro.kernels.upload_fused` fuses the
+threshold+accumulate pass with the rest of the upload for TPU; this module
+is the jnp reference and the pytree-level orchestration.
 """
 from __future__ import annotations
 

@@ -17,8 +17,8 @@ Pluggable pieces:
   * per-node compute/bandwidth via `NodeProfile` (replaces the seed
     implementation's scalar `node_time` array);
   * upload-pipeline backend — "reference" (pure-jnp `accumulator`/`aldp`,
-    bit-compatible with the sequential reference loop) or "pallas" (the fused
-    `sparsify`/`ldp_noise` kernels in node-batched form).
+    bit-compatible with the sequential reference loop) or "pallas" (the
+    node-batched fused upload kernel, `kernels.upload_fused`).
 
 With `key_mode="sequential"` the engine reproduces the sequential reference
 loop's per-node PRNG chain exactly (see `state.chain_node_keys`), which is

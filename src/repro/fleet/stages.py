@@ -11,8 +11,8 @@ Only the aggregation differs (barrier masked-mean vs staleness-aware
 arrival-order mixing), so the stages live here as module-level functions
 parameterized by `FleetConfig` with a pluggable backend: "reference"
 (pure-jnp `accumulator`/`aldp`, bit-compatible with the sequential
-trainer) or "pallas" (the node-batched fused `sparsify`/`ldp_noise`
-kernels).
+trainer) or "pallas" (the node-batched fused upload kernel,
+`kernels.upload_fused`, and the window-fold kernel).
 
 Every stage here is *shard-oblivious*: all math is per-node along the
 leading axis with no cross-node reduction, so the mesh-sharded engines
@@ -194,8 +194,8 @@ def _upload_pipeline_fused(cfg, deltas, residuals_c, k2s, need_nnz: bool):
 
 
 def node_noise_seeds(k2s) -> jnp.ndarray:
-    """Node-distinct int32 noise seeds folded from the per-node PRNG keys —
-    shared by the fused and unfused pallas ALDP paths."""
+    """Node-distinct int32 noise seeds folded from the per-node PRNG keys:
+    the seeds the fused upload kernel's in-kernel noise draws from."""
     raw = k2s
     if jnp.issubdtype(k2s.dtype, jax.dtypes.prng_key):   # new-style typed keys
         raw = jax.random.key_data(k2s)
@@ -347,18 +347,16 @@ def adaptive_throttle_update(throttle: jnp.ndarray, rejected: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# cohort flat layout (cached) + the pallas-backed cohort upload pipeline
+# cohort flat layout (cached), shared by the pallas stages
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
 class CohortLayout:
     """Static flat layout of a stacked cohort tree: leaf order, shapes,
     dtypes and start offsets in the concatenated (C, P) f32 view.  Built
-    once per (treedef, shapes, dtypes) via `cohort_layout` — the flatten /
-    unflatten closures and leaf boundaries used to be rebuilt on every
-    trace by each pipeline stage separately; now every pallas stage (the
-    fused pipeline, the unfused ALDP chain, nnz counting, the window fold)
-    shares one cached layout."""
+    once per (treedef, shapes, dtypes) via `cohort_layout`, so every
+    pallas stage (the fused upload, nnz counting, the window fold) shares
+    one cached layout."""
     treedef: object
     shapes: Tuple[Tuple[int, ...], ...]
     dtypes: Tuple[np.dtype, ...]
@@ -415,52 +413,6 @@ def cohort_layout(tree) -> CohortLayout:
     return _cohort_layout(treedef,
                           tuple(tuple(l.shape[1:]) for l in leaves),
                           tuple(np.dtype(l.dtype) for l in leaves))
-
-
-def flatten_cohort(tree):
-    """Stacked tree with leading cohort axis -> ((C, P) flat, unflatten)."""
-    layout = cohort_layout(tree)
-    return layout.flatten(tree), layout.unflatten
-
-
-def sparsify_pallas_cohort(deltas, residuals, ratio: float):
-    """Per-leaf DGC split via the node-batched `sparsify_fleet` kernel —
-    same per-leaf quantile threshold rule as `accum.accumulate_and_sparsify`,
-    but one kernel launch per leaf for the whole cohort."""
-    from ..kernels.sparsify import sparsify_fleet
-
-    def one_leaf(d, r):
-        c = d.shape[0]
-        df = d.reshape(c, -1).astype(jnp.float32)
-        rf = r.reshape(c, -1).astype(jnp.float32)
-        comb = df + rf
-        thr = jax.vmap(lambda v: accum.leaf_threshold(v, ratio))(comb)
-        up, newr = sparsify_fleet(df, rf, thr)
-        return up.reshape(d.shape).astype(d.dtype), newr.reshape(r.shape)
-
-    pairs = jax.tree.map(one_leaf, deltas, residuals)
-    up = jax.tree.map(lambda p: p[0], pairs,
-                      is_leaf=lambda x: isinstance(x, tuple))
-    newr = jax.tree.map(lambda p: p[1], pairs,
-                        is_leaf=lambda x: isinstance(x, tuple))
-    return up, newr
-
-
-def aldp_pallas_cohort(deltas, k2s, sigma: float, clip_s: float):
-    """Cohort ALDP via the node-batched `ldp_perturb_fleet` kernel: whole-
-    delta clip scale per node, in-kernel Gaussian noise (node-distinct
-    seeds folded from the per-node PRNG keys).  Kept as the unfused
-    comparator for `kernels.upload_fused` (benchmarks + property tests);
-    the engines' pallas backend runs the fused pipeline."""
-    from ..kernels.ldp_noise import ldp_perturb_fleet
-
-    layout = cohort_layout(deltas)
-    flat = layout.flatten(deltas)
-    norms = jnp.sqrt(jnp.sum(jnp.square(flat), axis=1))
-    scales = 1.0 / jnp.maximum(1.0, norms / clip_s)
-    out = ldp_perturb_fleet(flat, node_noise_seeds(k2s), scales, sigma,
-                            clip_s)
-    return layout.unflatten(out)
 
 
 # ---------------------------------------------------------------------------

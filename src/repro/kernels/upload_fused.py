@@ -1,12 +1,7 @@
 """Fused upload-pipeline megakernel — Pallas TPU kernel.
 
-The per-arrival upload pipeline (`fleet.stages.upload_pipeline`) used to be
-a dispatch chain over the flattened (C, P) cohort: a DGC sparsify kernel per
-leaf (`kernels.sparsify`), a standalone nonzero-count kernel
-(`kernels.wire_bytes`) so `repro.net` can price the wire message, a jnp
-norm reduction for the ALDP clip scale, and the clip+noise kernel
-(`kernels.ldp_noise`) — ~9 HBM passes over the cohort plus the flatten /
-concat glue between them.  This kernel fuses the whole thing into ONE pass:
+The per-arrival upload pipeline (`fleet.stages.upload_pipeline`) over the
+flattened (C, P) cohort is one kernel pass:
 
   read (delta, residual) block -> combined = delta + residual
     -> keep = |combined| >= per-leaf DGC threshold       (§4.1)
@@ -17,19 +12,25 @@ concat glue between them.  This kernel fuses the whole thing into ONE pass:
     -> upload  = clip_scale * upload + N(0, (sigma·S)^2)  (§4.2, Eq. 10)
   write (upload, residual', nnz)
 
-so wire-byte counting is free and noise never touches HBM.  Two reductions
-stay outside by data dependency: the per-leaf quantile *threshold* needs a
-sort over the whole leaf, and the clip scale needs the post-sparsify global
-L2 norm before any output element — both run as one jnp pre-pass over the
-`combined` cohort in `fleet.stages`.
+so wire-byte counting is free and noise never touches HBM: each node's
+Gaussian noise is generated on-core from a counter hash of the element
+index (murmur3 finalizer + Box–Muller), seeded per block as
+`seed + block·7919`.  Two reductions stay outside by data dependency: the
+per-leaf quantile *threshold* needs a sort over the whole leaf, and the
+clip scale needs the post-sparsify global L2 norm before any output
+element — both run as one jnp pre-pass over the `combined` cohort in
+`fleet.stages`.
 
-Parity contract (tested in tests/test_upload_fused.py): bit-equal to the
-unfused `sparsify_fleet` -> `nnz_fleet` -> `ldp_perturb_fleet` chain — same
-block decomposition, same per-block seeding (`seed + block·7919`), same
-counter-based Box–Muller streams — and float-close to the reference jnp
-pipeline at sigma=0.  Like the reference pipeline, noise is applied to
-*every* coordinate (the documented dense-noise simulation artifact); the
-nnz output prices the intended sparse wire message.
+Parity contract (tested in tests/test_upload_fused.py): the jnp mirror
+`upload_fused_reference` below gives the same residuals and nnz bit for
+bit and replays the noise stream bit for bit (`block_noise`); the noised
+upload may differ by ~1 ulp where XLA contracts the scale-multiply +
+noise-add into an FMA.  A one-leaf, noiseless call equals
+`kernels.ref.sparsify_flat_ref` exactly, and a sparsify-free, σ=0 call
+matches `kernels.ref.ldp_perturb_flat_ref`.  Like the reference pipeline,
+noise is applied to *every* coordinate (the documented dense-noise
+simulation artifact); the nnz output prices the intended sparse wire
+message.
 
 Grid is (node, block): shard-oblivious — every output depends only on its
 own node row, so the mesh engines call this inside `shard_map` unchanged.
@@ -46,8 +47,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import LANE, block_layout, interpret_mode, pad_blocks
-from .ldp_noise import _finalize_uniform, _hash_uniform
 from .wire_bytes import accumulate_nnz, nnz_out
+
+
+def _hash_uniform(seed: jnp.ndarray, stream: int, shape) -> jnp.ndarray:
+    """Counter-based uniform(0,1) from a murmur3-finalizer hash of the
+    per-element index — pure u32 VPU ops, identical on CPU interpret and TPU.
+    (pltpu.prng_random_bits has no CPU-interpret lowering in this jax build.)
+    """
+    rows = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
+    x = rows * jnp.uint32(shape[1]) + cols
+    x = x + seed.astype(jnp.uint32) * jnp.uint32(2654435761)
+    x = x + jnp.uint32((stream * 0x9E3779B9) & 0xFFFFFFFF)
+    return _finalize_uniform(x)
+
+
+def _finalize_uniform(x: jnp.ndarray) -> jnp.ndarray:
+    """murmur3 finalizer of u32 counters -> uniform [0, 1) f32.  The top 24
+    bits fit an int32 exactly, and the TPU has no u32 -> f32 convert, so the
+    cast goes through int32 (same values on every backend)."""
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x7FEB352D)
+    x = x ^ (x >> 15)
+    x = x * jnp.uint32(0x846CA68B)
+    x = x ^ (x >> 16)
+    return ((x >> 8).astype(jnp.int32).astype(jnp.float32)
+            / jnp.float32(1 << 24))
 
 
 def _fused_kernel(*refs, sigma_s: float, apply_ldp: bool, do_sparsify: bool,
@@ -124,7 +150,7 @@ def upload_fused_fleet(flat: jnp.ndarray,
     boundaries: static start offset of each leaf in the flat layout.
 
     Returns (upload (C, N), residual' (C, N) or None, nnz (C,) i32 or
-    None) — bit-equal to the unfused sparsify/nnz/ldp kernel chain.
+    None).
     """
     k, n = flat.shape
     rows, block_rows, nb = block_layout(n, block_rows)

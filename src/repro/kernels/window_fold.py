@@ -90,8 +90,19 @@ def window_fold_fleet(p_flat: jnp.ndarray, om_flat: jnp.ndarray,
 def window_fold_reference(p_flat: jnp.ndarray, om_flat: jnp.ndarray,
                           gates: jnp.ndarray, a: jnp.ndarray,
                           b: jnp.ndarray):
-    """Pure-jnp mirror of `window_fold_fleet` (a lax.scan) — the fallback
-    and parity oracle; bit-equal for f32 inputs."""
+    """Pure-jnp mirror of `window_fold_fleet` (a lax.scan) — the parity
+    oracle; bit-equal for f32 inputs.
+
+    It folds the vector zero-padded to whole LANE rows, as the kernel
+    does, not the bare n-vector, so that XLA:CPU compiles
+    `a·params + b·omega` to the same vector loop as the interpreted
+    kernel's block and contracts it into the same FMA.  On the bare
+    vector, one arrival of n=1 compiles to scalar code that contracts the
+    other product, one ulp off the kernel."""
+    n = p_flat.shape[0]
+    pad = -n % LANE
+    p = jnp.pad(p_flat.astype(jnp.float32), (0, pad))
+    om = jnp.pad(om_flat.astype(jnp.float32), ((0, 0), (0, pad)))
 
     def body(cur, inp):
         om_i, g_i, a_i, b_i = inp
@@ -100,7 +111,6 @@ def window_fold_reference(p_flat: jnp.ndarray, om_flat: jnp.ndarray,
         return cur, cur
 
     final, seq = jax.lax.scan(
-        body, p_flat.astype(jnp.float32),
-        (om_flat.astype(jnp.float32), gates.astype(bool),
-         a.astype(jnp.float32), b.astype(jnp.float32)))
-    return final, seq
+        body, p, (om, gates.astype(bool), a.astype(jnp.float32),
+                  b.astype(jnp.float32)))
+    return final[:n], seq[:, :n]

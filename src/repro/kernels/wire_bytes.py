@@ -3,7 +3,7 @@
 `repro.net` prices every upload from its nonzero count (sparse codecs
 encode exactly the nonzero coordinates).  Counting nonzeros over a stacked
 (K, P) cohort naively reads the whole cohort once per reduction step; this
-kernel mirrors the `sparsify.py` fleet idiom — grid (node, block), one
+kernel uses the fleet kernels' grid (node, block), one
 VMEM pass per block — and accumulates each node's count into a revisited
 lane-dense (1, 1, NNZ_LANES) output block, so the whole cohort is priced
 in a single launch.

@@ -21,8 +21,8 @@ Registry (`get_codec`):
                           |error| ≤ scale/2 per element).
 
 Node-batched accounting (`batched_encoded_bytes`) counts nonzeros across
-a stacked (K, P) cohort — one fused Pallas pass (`kernels.wire_bytes`,
-mirroring `kernels/sparsify.py`) or a vectorized jnp fallback — and maps
+a stacked (K, P) cohort — one fused Pallas pass (`kernels.wire_bytes`)
+or a vectorized jnp fallback — and maps
 the counts through `Codec.nbytes`.
 
 `analytic_upload_bytes` is the pre-`repro.net` estimate, kept as the

@@ -12,8 +12,8 @@ The observability layer under the whole fleet/net/kernel stack:
     `read_jsonl`), `MemorySink` for tests, and the Chrome-trace/Perfetto
     exporter (`chrome_trace`/`write_chrome_trace`);
   * `timers`  — host stage spans on the profiler's clock (`host_span`,
-    `timed_stage`, fenced only under ``stage_timings``), ``py.gc`` spans
-    (`GcSpans`) and the kernel profiling primitive (`bench_kernel`);
+    `timed_stage`, fenced only under ``stage_timings``) and ``py.gc``
+    spans (`GcSpans`);
   * `analysis` — `FleetAnalytics`, the streaming trace-analytics sink
     folding arrival/window/upload/verdict events into derived fleet
     indicators (straggler scores, occupancy/skew, byte accounting,
@@ -44,5 +44,5 @@ from .report import postmortem_md, run_diff_md  # noqa: F401
 from .sinks import (OBS_SCHEMA_VERSION, JsonlSink, JsonlWriter,  # noqa: F401
                     MemorySink, Sink, chrome_trace, read_events,
                     read_jsonl, write_chrome_trace)
-from .timers import (GcSpans, bench_kernel, fence,  # noqa: F401
-                     host_span, timed_stage)
+from .timers import (GcSpans, fence, host_span,  # noqa: F401
+                     timed_stage)

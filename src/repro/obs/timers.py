@@ -1,4 +1,4 @@
-"""Host stage spans on the profiler's clock, and the kernels profiling mode.
+"""Host stage spans on the profiler's clock.
 
 Every host stage of a record (the round or window, the device program's
 dispatch, the read-backs, net draw/commit, evaluation, the accountant)
@@ -22,21 +22,15 @@ taken the annotation does nothing.  Two modes add to it:
 `GcSpans` puts Python's garbage collections on the same clock, as
 ``py.gc`` spans.
 
-`bench_kernel(name, fn, *args)` is the microbenchmark primitive
-`benchmarks/kernels_micro.py` consumes: warmup + fenced timing loop,
-µs/call, and a counter event + histogram sample into the tracer.
-
 `fence` accepts any pytree (jax arrays, tuples, dicts) and tolerates
 plain host values, so call sites don't special-case output shapes.
 """
 from __future__ import annotations
 
 import gc
-import time
 from typing import Any, Optional
 
 from .events import _NULL_SPAN, Tracer, get_tracer
-from .metrics import SECONDS_EDGES
 
 
 def fence(x: Any) -> Any:
@@ -128,33 +122,3 @@ class GcSpans:
     def __exit__(self, *exc):
         gc.callbacks.remove(self._callback)
         return False
-
-
-# ---------------------------------------------------------------------------
-# kernels profiling mode
-# ---------------------------------------------------------------------------
-
-def bench_kernel(name: str, fn, *args, iters: int = 3, warmup: int = 1,
-                 tracer: Optional[Tracer] = None) -> float:
-    """Fenced kernel microbenchmark: µs per call over ``iters`` timed
-    iterations after ``warmup`` untimed ones (compilation + first-touch).
-
-    When the (global or injected) tracer is enabled, each measurement
-    lands in the stream as a ``kernel.<name>`` counter event (value =
-    µs/call, tags carry iters) and a shared ``kernel.us_per_call``
-    histogram sample — the kernels profiling mode
-    `benchmarks/kernels_micro.py --profile` turns on.
-    """
-    tracer = tracer if tracer is not None else get_tracer()
-    for _ in range(max(1, warmup)):
-        fence(fn(*args))
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = fn(*args)
-    fence(out)
-    us = (time.perf_counter() - t0) / iters * 1e6
-    if tracer.enabled:
-        tracer.counter(f"kernel.{name}", us, iters=iters)
-        tracer.metrics.histogram("kernel.us_per_call",
-                                 [e * 1e6 for e in SECONDS_EDGES]).observe(us)
-    return us

@@ -278,35 +278,45 @@ def test_cohort_sampling_updates_only_sampled_residuals():
 
 
 # ---------------------------------------------------------------------------
-# pallas backend (node-batched sparsify / ldp_noise kernels)
+# pallas backend (the node-batched fused upload kernel)
 # ---------------------------------------------------------------------------
 
 def test_ldp_fleet_kernel_matches_flat():
-    from repro.kernels.ldp_noise import ldp_perturb_flat, ldp_perturb_fleet
+    """Noise only: node row i of a 3-node fused upload call is a 1-node
+    call with node i's seed and clip scale."""
+    from repro.kernels.upload_fused import upload_fused_fleet
     rng = np.random.default_rng(0)
     flat = jnp.asarray(rng.normal(size=(3, 2000)).astype(np.float32))
     seeds = jnp.array([11, 22, 33], jnp.int32)
     scales = jnp.array([0.5, 1.0, 0.25], jnp.float32)
-    batched = ldp_perturb_fleet(flat, seeds, scales, 0.3, 1.5)
+    batched, _, _ = upload_fused_fleet(flat, None, None, seeds, scales,
+                                       0.3, 1.5)
     for i in range(3):
-        single = ldp_perturb_flat(flat[i], seeds[i], scales[i], 0.3, 1.5)
+        single, _, _ = upload_fused_fleet(flat[i:i + 1], None, None,
+                                          seeds[i:i + 1], scales[i:i + 1],
+                                          0.3, 1.5)
+        # the two programs may contract scale·x + noise into an FMA
+        # differently: one ulp
         np.testing.assert_allclose(np.asarray(batched[i]),
-                                   np.asarray(single), atol=1e-6)
+                                   np.asarray(single[0]), atol=1e-6)
 
 
-def test_sparsify_fleet_kernel_matches_flat():
-    from repro.kernels.sparsify import sparsify_flat, sparsify_fleet
+def test_dgc_fleet_kernel_matches_flat():
+    """Sparsify only: node row i of a 3-node fused upload call is a
+    1-node call with node i's threshold."""
+    from repro.kernels.upload_fused import upload_fused_fleet
     rng = np.random.default_rng(1)
     g = jnp.asarray(rng.normal(size=(3, 1500)).astype(np.float32))
     r = jnp.asarray(rng.normal(size=(3, 1500)).astype(np.float32))
-    thr = jnp.array([0.5, 1.0, 2.0], jnp.float32)
-    up_b, nr_b = sparsify_fleet(g, r, thr)
+    thr = jnp.array([[0.5], [1.0], [2.0]], jnp.float32)
+    up_b, nr_b, _ = upload_fused_fleet(g, r, thr, None, None, 0.0, 1.0)
     for i in range(3):
-        up, nr = sparsify_flat(g[i], r[i], thr[i])
-        np.testing.assert_allclose(np.asarray(up_b[i]), np.asarray(up),
-                                   atol=1e-6)
-        np.testing.assert_allclose(np.asarray(nr_b[i]), np.asarray(nr),
-                                   atol=1e-6)
+        up, nr, _ = upload_fused_fleet(g[i:i + 1], r[i:i + 1],
+                                       thr[i:i + 1], None, None, 0.0, 1.0)
+        np.testing.assert_array_equal(np.asarray(up_b[i]),
+                                      np.asarray(up[0]))
+        np.testing.assert_array_equal(np.asarray(nr_b[i]),
+                                      np.asarray(nr[0]))
 
 
 def test_pallas_backend_matches_reference_without_noise():
@@ -327,11 +337,14 @@ def test_pallas_backend_matches_reference_without_noise():
 def test_pallas_backend_noise_magnitude():
     """With σ>0 the pallas noise source differs from jax.random but its
     statistics must match N(0, (σS)²) on the uploaded deltas."""
-    from repro.fleet.stages import aldp_pallas_cohort
+    from repro.fleet.engine import FleetConfig
+    from repro.fleet.stages import upload_pipeline
     zeros = {"w": jnp.zeros((4, 4096))}
     k2s = jax.random.split(jax.random.PRNGKey(0), 4)
     sigma, clip_s = 0.5, 2.0
-    out = aldp_pallas_cohort(zeros, k2s, sigma, clip_s)["w"]
+    cfg = FleetConfig(sigma=sigma, clip_s=clip_s, sparsify_ratio=1.0,
+                      backend="pallas")
+    out = upload_pipeline(cfg, zeros, zeros, k2s)[0]["w"]
     stds = np.asarray(out).std(axis=1)
     np.testing.assert_allclose(stds, sigma * clip_s, rtol=0.1)
     # node-distinct seeds => node-distinct noise

@@ -6,17 +6,17 @@ import pytest
 
 from repro.kernels import LANE, block_layout, interpret_mode
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ldp_noise import _finalize_uniform, ldp_perturb_flat
-from repro.kernels.ops import (aldp_perturb_pallas, attention_pallas,
-                               sparsify_pallas)
+from repro.kernels.ops import attention_pallas
 from repro.kernels.ref import (flash_attention_ref, ldp_perturb_flat_ref,
                                selective_scan_ref, sparsify_flat_ref,
                                ssd_scan_ref)
 from repro.kernels.selective_scan import selective_scan
 from repro.kernels.ssd_scan import ssd_scan
-from repro.kernels.sparsify import sparsify_flat
-from repro.core.aldp import aldp_perturb, clip_by_global_norm
+from repro.kernels.upload_fused import _finalize_uniform, upload_fused_fleet
+from repro.core.aldp import clip_by_global_norm
 from repro.core.accumulator import accumulate_and_sparsify, init_residual
+from repro.fleet import stages
+from repro.fleet.engine import FleetConfig
 
 TOLS = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
@@ -83,40 +83,50 @@ def test_attention_pallas_model_layout():
 
 
 # ---------------------------------------------------------------------------
-# ldp_noise kernel
+# upload_fused kernel: the ALDP clip + noise stage
 # ---------------------------------------------------------------------------
+
+def _noise_only(flat, seeds, scales, sigma, clip_s):
+    """The fused upload kernel with sparsification off: (C, N) deltas ->
+    scales[:, None]·deltas + N(0, (σS)²)."""
+    up, _, _ = upload_fused_fleet(flat, None, None, seeds,
+                                  jnp.asarray(scales, jnp.float32), sigma,
+                                  clip_s)
+    return up
+
 
 @pytest.mark.parametrize("n", [100, 1024, 4097, 300000])
 def test_ldp_kernel_deterministic_path(n):
+    """σ=0: the kernel's ALDP stage is the clip scale alone."""
     g = jax.random.normal(jax.random.PRNGKey(0), (n,), jnp.float32)
-    out = ldp_perturb_flat(g, jnp.int32(3), jnp.float32(0.25), 0.0, 1.0)
+    out = _noise_only(g[None], None, [0.25], 0.0, 1.0)[0]
     ref = ldp_perturb_flat_ref(g, jnp.float32(0.25), None, 0.0, 1.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
 
 
 def test_ldp_kernel_noise_statistics():
-    out = ldp_perturb_flat(jnp.zeros(500000), jnp.int32(11), jnp.float32(1.0),
-                           0.3, 2.0)
-    x = np.asarray(out)
+    """The in-kernel noise on a zero delta is N(0, (σS)²) — mean, std,
+    kurtosis — and two node seeds draw uncorrelated streams."""
+    out = _noise_only(jnp.zeros((2, 500000)), jnp.array([11, 12], jnp.int32),
+                      [1.0, 1.0], 0.3, 2.0)
+    x = np.asarray(out[0])
     assert abs(x.mean()) < 5e-3
     assert abs(x.std() - 0.6) < 5e-3
     kurt = ((x - x.mean()) ** 4).mean() / x.std() ** 4
     assert abs(kurt - 3.0) < 0.1          # gaussianity
-    out2 = ldp_perturb_flat(jnp.zeros(500000), jnp.int32(12), jnp.float32(1.0),
-                            0.3, 2.0)
-    assert abs(float(np.corrcoef(x, np.asarray(out2))[0, 1])) < 0.01
+    assert abs(float(np.corrcoef(x, np.asarray(out[1]))[0, 1])) < 0.01
 
 
 def test_ldp_noise_stream_independent_of_block_clamp():
     """`block_layout` clamps block_rows to the rows a vector needs; a vector
     that fits one block keeps its in-block indices, so its noise is the
     prefix of a full 256-row block's noise."""
-    full = ldp_perturb_flat(jnp.zeros(256 * LANE), jnp.int32(9),
-                            jnp.float32(1.0), 0.3, 1.0)
+    seed = jnp.array([9], jnp.int32)
+    full = _noise_only(jnp.zeros((1, 256 * LANE)), seed, [1.0], 0.3, 1.0)
     for n in (100, 20490, 70000):
-        out = ldp_perturb_flat(jnp.zeros(n), jnp.int32(9), jnp.float32(1.0),
-                               0.3, 1.0)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(full)[:n])
+        out = _noise_only(jnp.zeros((1, n)), seed, [1.0], 0.3, 1.0)
+        np.testing.assert_array_equal(np.asarray(out[0]),
+                                      np.asarray(full[0])[:n])
 
 
 def test_uniform_cast_through_int32_is_exact():
@@ -148,30 +158,42 @@ def test_block_layout_clamps_to_the_vector(n, block_rows, want):
 
 
 def test_ldp_ops_matches_core_clipping():
+    """The fused kernel applying `core.aldp.clip_by_global_norm`'s scale
+    to the flattened tree gives that clip, leaf by leaf; the flat layout's
+    norm is the tree's."""
     key = jax.random.PRNGKey(4)
     tree = {"a": jax.random.normal(key, (64, 32)) * 5,
             "b": jax.random.normal(key, (100,))}
-    pk, nrm_k = aldp_perturb_pallas(tree, jnp.int32(0), sigma=0.0, clip_s=0.7)
     pc, nrm_c = clip_by_global_norm(tree, 0.7)
+    stacked = jax.tree.map(lambda x: x[None], tree)
+    layout = stages.cohort_layout(stacked)
+    flat = layout.flatten(stacked)
+    nrm_k = jnp.sqrt(jnp.sum(jnp.square(flat)))
     assert float(abs(nrm_k - nrm_c)) < 1e-3
+    scale = 1.0 / jnp.maximum(1.0, nrm_c / 0.7)
+    pk = layout.unflatten(_noise_only(flat, None, scale[None], 0.0, 0.7))
     for a, b in zip(jax.tree.leaves(pk), jax.tree.leaves(pc)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(a[0]), np.asarray(b),
+                                   rtol=1e-5)
 
 
 # ---------------------------------------------------------------------------
-# sparsify kernel
+# upload_fused kernel: the DGC sparsify stage
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("n", [64, 1025, 50000])
 @pytest.mark.parametrize("thr", [0.0, 0.5, 2.0])
 def test_sparsify_kernel_exact(n, thr):
+    """One leaf, no noise: the kernel's upload and residual are the
+    reference split's, bit for bit."""
     key = jax.random.PRNGKey(5)
     g = jax.random.normal(key, (n,), jnp.float32)
     r = jax.random.normal(jax.random.PRNGKey(6), (n,), jnp.float32)
-    up, nr = sparsify_flat(g, r, jnp.float32(thr))
+    up, nr, _ = upload_fused_fleet(g[None], r[None], jnp.array([[thr]]),
+                                   None, None, 0.0, 1.0)
     upr, nrr = sparsify_flat_ref(g, r, jnp.float32(thr))
-    np.testing.assert_allclose(np.asarray(up), np.asarray(upr), atol=1e-7)
-    np.testing.assert_allclose(np.asarray(nr), np.asarray(nrr), atol=1e-7)
+    np.testing.assert_array_equal(np.asarray(up[0]), np.asarray(upr))
+    np.testing.assert_array_equal(np.asarray(nr[0]), np.asarray(nrr))
 
 
 # ---------------------------------------------------------------------------
@@ -266,19 +288,25 @@ def test_ssd_scan_matches_model_mamba2():
 
 
 def test_sparsify_ops_matches_accumulator():
-    key = jax.random.PRNGKey(7)
-    g = {"w": jax.random.normal(key, (400,)), "b": jax.random.normal(key, (30,))}
-    r = init_residual(g)
-    up_k, r_k = sparsify_pallas(g, r, ratio=0.2)
-    up_j, r_j, frac = accumulate_and_sparsify(r, g, 0.2)
-    # same keep-fraction and conservation; thresholds computed identically
-    kept_k = sum(float((jnp.asarray(u) != 0).sum()) for u in jax.tree.leaves(up_k))
-    kept_j = sum(float((jnp.asarray(u) != 0).sum()) for u in jax.tree.leaves(up_j))
-    assert abs(kept_k - kept_j) <= 2
-    tot_k = jax.tree.map(lambda a, b: a + b, up_k, r_k)
-    tot_in = jax.tree.map(lambda a, b: a + b, g, r)
-    for x, y in zip(jax.tree.leaves(tot_k), jax.tree.leaves(tot_in)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5)
+    """The pallas backend's upload pipeline at σ=0 splits every node's
+    leaves as `accumulate_and_sparsify` does (the same per-leaf quantile
+    threshold), bit for bit, residuals included."""
+    c = 3
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    g = {"w": jax.random.normal(ks[0], (c, 400)),
+         "b": jax.random.normal(ks[1], (c, 30))}
+    r = jax.tree.map(lambda x: jax.random.normal(ks[2], x.shape) * 0.1,
+                     init_residual(g))
+    cfg = FleetConfig(sigma=0.0, sparsify_ratio=0.2, backend="pallas")
+    up_k, r_k, _ = stages.upload_pipeline(
+        cfg, g, r, jax.random.split(jax.random.PRNGKey(8), c))
+    for i in range(c):
+        node = lambda t: jax.tree.map(lambda x: x[i], t)
+        up_j, r_j, _ = accumulate_and_sparsify(node(r), node(g), 0.2)
+        for got, want in zip(jax.tree.leaves(node(up_k)) +
+                             jax.tree.leaves(node(r_k)),
+                             jax.tree.leaves(up_j) + jax.tree.leaves(r_j)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------

@@ -3,8 +3,7 @@
 Tiers:
   * unit        — TraceEvent round trip, disabled-tracer no-ops, span
     nesting/seq order, metrics registry semantics, JSONL torn-tail
-    handling, Chrome-trace structure, `bench_kernel`/`timed_stage`
-    gating;
+    handling, Chrome-trace structure, `timed_stage` gating;
   * api         — `ObsSpec` validation in `compile_plan`, crash-safe
     `append_json_records`;
   * acceptance  — a traced async run over a lossy network produces a
@@ -31,8 +30,8 @@ from repro import api
 from repro import obs
 from repro.net import LinkProfile, NetSim
 from repro.obs import (MemorySink, MetricsRegistry, TraceEvent, Tracer,
-                       bench_kernel, chrome_trace, read_events, read_jsonl,
-                       timed_stage, use_tracer)
+                       chrome_trace, read_events, read_jsonl, timed_stage,
+                       use_tracer)
 
 
 # ---------------------------------------------------------------------------
@@ -296,18 +295,6 @@ def test_timed_stage_gating(monkeypatch):
     assert fenced == [{"a": 1}]
     (ev,) = sink.events
     assert ev.name == "stage.round.device" and ev.tags == {"round": 3}
-
-
-def test_bench_kernel_emits_counter_and_histogram():
-    import jax.numpy as jnp
-    sink = MemorySink()
-    tr = Tracer([sink])
-    us = bench_kernel("dot", lambda a: a @ a, jnp.eye(8), iters=2, tracer=tr)
-    assert us > 0.0
-    (ev,) = [e for e in sink.events if e.kind == "counter"]
-    assert ev.name == "kernel.dot" and ev.value == pytest.approx(us)
-    snap = tr.metrics.snapshot()["kernel.us_per_call"]
-    assert snap["count"] == 1
 
 
 # ---------------------------------------------------------------------------

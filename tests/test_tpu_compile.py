@@ -20,8 +20,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.ldp_noise import ldp_perturb_fleet
-from repro.kernels.sparsify import sparsify_fleet
 from repro.kernels.upload_fused import upload_fused_fleet
 from repro.kernels.window_fold import window_fold_fleet
 from repro.kernels.wire_bytes import nnz_fleet
@@ -30,8 +28,7 @@ from repro.models.cnn import cnn_flat_layout
 COHORT = 64
 # the `name=` each kernel's pallas_call gives its custom call
 KERNEL_NAMES = {"upload_fused": "upload_fused", "window_fold": "window_fold",
-                "nnz": "wire_bytes", "sparsify": "sparsify",
-                "ldp_perturb": "ldp_noise"}
+                "nnz": "wire_bytes"}
 
 
 @pytest.fixture(scope="module")
@@ -73,18 +70,10 @@ def _calls(c, p, bounds):
             [((p,), f32), ((c, p), f32), ((c,), i32), ((c,), f32),
              ((c,), f32)]),
         "nnz": (lambda f: nnz_fleet(f, interpret=False), [((c, p), f32)]),
-        "sparsify": (
-            lambda g, r, t: sparsify_fleet(g, r, t, interpret=False),
-            [((c, p), f32), ((c, p), f32), ((c,), f32)]),
-        "ldp_perturb": (
-            lambda f, s, sc: ldp_perturb_fleet(f, s, sc, 0.05, 1.0,
-                                               interpret=False),
-            [((c, p), f32), ((c,), i32), ((c,), f32)]),
     }
 
 
-@pytest.mark.parametrize("name", ["upload_fused", "window_fold", "nnz",
-                                  "sparsify", "ldp_perturb"])
+@pytest.mark.parametrize("name", ["upload_fused", "window_fold", "nnz"])
 def test_fleet_kernel_compiles_for_v5e(one_chip, name):
     p, bounds = cnn_flat_layout((28, 28))
     assert len(bounds) == 6
